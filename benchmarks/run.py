@@ -28,6 +28,7 @@ from typing import Dict, List
 # the gate's metric detector — sharing it guarantees the medians taken
 # here cover exactly the metrics check_regression.py will compare
 from benchmarks.check_regression import _is_walltime
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def machine_fingerprint() -> Dict:
@@ -114,6 +115,7 @@ def main(argv=None) -> None:
         print(f"unknown suite(s): {', '.join(unknown)}; "
               f"available: {', '.join(modules)}", file=sys.stderr)
         raise SystemExit(2)
+    enable_compile_cache()
     want = list(args.suites) or list(modules)
     for name in want:
         mod = modules[name]

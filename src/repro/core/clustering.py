@@ -83,11 +83,10 @@ def _assign(x, centroids, use_kernel: bool = False,
     dax = _row_shard_axes(mesh, x.shape[0])
     if dax is None:
         return kmeans_assign(x, centroids, interpret=None)
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda xs, c: kmeans_assign(xs, c, interpret=None),
         mesh=mesh, in_specs=(P(dax, None), P(None, None)),
-        out_specs=(P(dax), P(dax)), check_rep=False)
+        out_specs=(P(dax), P(dax)), check_vma=False)
     return fn(x, centroids)
 
 
@@ -104,17 +103,16 @@ def _update(x, centroids, valid, use_kernel: bool = False,
     dax = _row_shard_axes(mesh, x.shape[0])
     if dax is None:
         return kmeans_update(x, centroids, valid, interpret=None)
-    from jax.experimental.shard_map import shard_map
 
     def body(xs, c, vs):
         s, n, i = kmeans_update(xs, c, vs, interpret=None)
         return (jax.lax.psum(s, dax), jax.lax.psum(n, dax),
                 jax.lax.psum(i, dax))
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(dax, None), P(None, None), P(dax)),
-                   out_specs=(P(None, None), P(None), P()),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(dax, None), P(None, None), P(dax)),
+                       out_specs=(P(None, None), P(None), P()),
+                       check_vma=False)
     v = (jnp.ones((x.shape[0],), jnp.float32) if valid is None else valid)
     return fn(x, centroids, v)
 

@@ -17,8 +17,3 @@
 #                        runs on CPU, used by parity tests and benchmarks
 # The flag is threaded as a static argument (baked into jax.jit partials),
 # so switching impl never retraces existing entry points.
-from jax.experimental.pallas import tpu as _pltpu
-
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    _pltpu.TPUCompilerParams

@@ -51,6 +51,6 @@ def kmeans_update(x, centroids, valid=None, block_n: int = 1024,
         x = jnp.pad(x, ((0, pad), (0, 0)))
         valid = jnp.pad(valid.astype(jnp.float32), ((0, pad),))
     sums, counts, inertia = kmeans_update_pallas(
-        x, centroids, valid.astype(jnp.float32), block_n=bn,
+        x, centroids, valid.astype(jnp.float32)[:, None], block_n=bn,
         interpret=interpret)
-    return sums, counts, inertia[0]
+    return sums, counts[0], inertia[0, 0]

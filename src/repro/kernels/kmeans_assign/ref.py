@@ -17,8 +17,8 @@ def kmeans_update_reference(x, centroids, valid):
     """Fused k-means step oracle: assignment + masked segment reduction.
 
     x: (N,d); centroids: (K,d); valid: (N,) mask. Returns
-    (sums (K,d) f32, counts (K,) f32, inertia (1,) f32) — matching
-    `kmeans_update_pallas` (fp32 accumulators everywhere).
+    (sums (K,d) f32, counts (K,) f32, inertia (1,) f32) — the same
+    reductions as `kmeans_update_pallas` (fp32 accumulators everywhere).
     """
     import jax
     xf = x.astype(jnp.float32)

@@ -26,7 +26,7 @@ kernel re-derives the (N, M) score matrix and softmax in VMEM per
 Because the mask is additive, masked and padded keys have P exactly 0
 (fp32 exp underflow below either NEG_INF tier), so their dK/dV/db are
 exactly zero — gradients can never leak into masked set slots. db is
-emitted per head as (B, H, M) and reduced over heads by the wrapper.
+emitted per head as (B, H, 1, M) and reduced over heads by the wrapper.
 
 Numerics policy (bf16 inputs at scale): all matmuls accumulate in fp32
 (`preferred_element_type`), and SAB probabilities stay fp32 between the
@@ -37,8 +37,9 @@ tensors round to the input dtype at kernel boundaries.
 
 Grid: (B, H). Blocks:
   q/dq:  (1, 1, N, dh) VMEM tiles       k/v/dk/dv: (1, 1, M, dh)
-  bias:  (1, M) fp32, shared across heads (index_map drops h)
-  o/do:  (1, 1, N, dh)                  db: (1, 1, M) fp32 per head
+  bias:  (1, 1, M) fp32 over (B, 1, M), shared across heads (index_map
+         drops h); the unit axis keeps the block's last two dims legal
+  o/do:  (1, 1, N, dh)                  db: (1, 1, 1, M) fp32 per head
 """
 from __future__ import annotations
 
@@ -47,8 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0 ** 30
 
@@ -59,7 +59,7 @@ def _softmax_from_refs(q_ref, k_ref, b_ref, scale: float):
     k = k_ref[0, 0].astype(jnp.float32)                       # (M, dh)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    s = s + b_ref[0][None, :]                                 # (N, M) VMEM
+    s = s + b_ref[0]                                          # (N, M) VMEM
     s = s - jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s)
     return q, k, p / jnp.sum(p, axis=-1, keepdims=True)
@@ -94,7 +94,7 @@ def _set_attn_bwd_kernel(q_ref, k_ref, v_ref, b_ref, do_ref,
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
-    db_ref[0, 0] = jnp.sum(ds, axis=0)                        # (M,) this head
+    db_ref[0, 0] = jnp.sum(ds, axis=0, keepdims=True)         # (1, M) this head
 
 
 def _fwd_call(q, k, v, key_bias, interpret: bool):
@@ -108,12 +108,12 @@ def _fwd_call(q, k, v, key_bias, interpret: bool):
             pl.BlockSpec((1, 1, N, dh), qkv_tile),
             pl.BlockSpec((1, 1, M, dh), qkv_tile),
             pl.BlockSpec((1, 1, M, dh), qkv_tile),
-            pl.BlockSpec((1, M), lambda b, h: (b, 0)),
+            pl.BlockSpec((1, 1, M), lambda b, h: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, N, dh), qkv_tile),
         out_shape=jax.ShapeDtypeStruct((B, H, N, dh), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(q, k, v, key_bias)
 
@@ -126,7 +126,7 @@ def _bwd_call(q, k, v, key_bias, do, interpret: bool):
         jax.ShapeDtypeStruct((B, H, N, dh), q.dtype),      # dq
         jax.ShapeDtypeStruct((B, H, M, dh), k.dtype),      # dk
         jax.ShapeDtypeStruct((B, H, M, dh), v.dtype),      # dv
-        jax.ShapeDtypeStruct((B, H, M), jnp.float32),      # db per head
+        jax.ShapeDtypeStruct((B, H, 1, M), jnp.float32),   # db per head
     )
     return pl.pallas_call(
         functools.partial(_set_attn_bwd_kernel, scale=dh ** -0.5),
@@ -135,18 +135,18 @@ def _bwd_call(q, k, v, key_bias, do, interpret: bool):
             pl.BlockSpec((1, 1, N, dh), qkv_tile),
             pl.BlockSpec((1, 1, M, dh), qkv_tile),
             pl.BlockSpec((1, 1, M, dh), qkv_tile),
-            pl.BlockSpec((1, M), lambda b, h: (b, 0)),
+            pl.BlockSpec((1, 1, M), lambda b, h: (b, 0, 0)),
             pl.BlockSpec((1, 1, N, dh), qkv_tile),
         ],
         out_specs=(
             pl.BlockSpec((1, 1, N, dh), qkv_tile),
             pl.BlockSpec((1, 1, M, dh), qkv_tile),
             pl.BlockSpec((1, 1, M, dh), qkv_tile),
-            pl.BlockSpec((1, 1, M), lambda b, h: (b, h, 0)),
+            pl.BlockSpec((1, 1, 1, M), lambda b, h: (b, h, 0, 0)),
         ),
         out_shape=out_shapes,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(q, k, v, key_bias, do)
 
@@ -180,4 +180,4 @@ def set_attention_pallas(q, k, v, key_bias, *, interpret: bool = False):
     (B,H,N,dh) in q.dtype. Differentiable: the custom VJP runs the fused
     backward kernel (see module docstring), so impl="pallas" works for
     training, not just inference."""
-    return _set_attention(q, k, v, key_bias, interpret)
+    return _set_attention(q, k, v, key_bias[:, None, :], interpret)

@@ -20,7 +20,7 @@ def wkv_chunked(r, k, v, w, beta, state: Optional[jnp.ndarray] = None,
         state = jnp.zeros((B, H, dh, dh), jnp.float32)
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, S, dh)  # noqa: E731
     rb, kb, vb, wb = fold(r), fold(k), fold(v), fold(w)
-    bb = beta.transpose(0, 2, 1).reshape(B * H, S)
+    bb = beta.transpose(0, 2, 1).reshape(B * H, S, 1)
     sb = state.reshape(B * H, dh, dh)
     # pad sequence to a chunk multiple (kernel requires divisibility)
     c = min(chunk, S) if S % min(chunk, S) == 0 else S

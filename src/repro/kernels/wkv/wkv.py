@@ -8,7 +8,7 @@ VPU ops plus dh-wide reductions. Sequence chunks are a sequential grid
 dimension ("arbitrary"), batch×head is parallel.
 
 Grid: (B*H, S // chunk). Blocks:
-  r/k/v/w: (1, chunk, dh) VMEM tiles      beta: (1, chunk)
+  r/k/v/w: (1, chunk, dh) VMEM tiles      beta: (1, chunk, 1)
   y:       (1, chunk, dh) output tile
   S_out:   (1, dh, dh) written on the last chunk
 Scratch:   S (dh, dh) fp32 — persists across the chunk dimension.
@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import CompilerParams as _CompilerParams
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, b_ref, s0_ref,
@@ -39,11 +37,13 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, b_ref, s0_ref,
         kt = k_ref[0, t, :].astype(jnp.float32)
         vt = v_ref[0, t, :].astype(jnp.float32)
         wt = w_ref[0, t, :].astype(jnp.float32)
-        bt = b_ref[0, t].astype(jnp.float32)
+        bt = b_ref[0, pl.ds(t, 1), :].astype(jnp.float32)  # (1, 1)
         S = S * wt[:, None]                          # decay rows (k dim)
         sk = jnp.sum(S * kt[:, None], axis=0)        # Sᵀ k  (dh_v,)
         delta = vt - sk
-        S = S + bt * (kt[:, None] * delta[None, :])  # rank-1 update
+        # β scales the (1, dh) row first: Mosaic broadcasts a (1, 1)
+        # value along lanes or sublanes, not both at once
+        S = S + kt[:, None] * (bt * delta[None, :])  # rank-1 update
         y = jnp.sum(S * rt[:, None], axis=0)         # Sᵀ r
         y_ref[0, t, :] = y.astype(y_ref.dtype)
         return S
@@ -59,7 +59,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, b_ref, s0_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv_pallas(r, k, v, w, beta, state, *, chunk: int = 128,
                interpret: bool = False):
-    """r,k,v,w: (BH, S, dh); beta: (BH, S); state: (BH, dh, dh) fp32.
+    """r,k,v,w: (BH, S, dh); beta: (BH, S, 1); state: (BH, dh, dh) fp32.
 
     Returns (y (BH,S,dh) fp32, final state (BH,dh,dh) fp32)."""
     BH, S, dh = r.shape
@@ -80,7 +80,7 @@ def wkv_pallas(r, k, v, w, beta, state, *, chunk: int = 128,
             pl.BlockSpec((1, chunk, dh), tile),
             pl.BlockSpec((1, chunk, dh), tile),
             pl.BlockSpec((1, chunk, dh), tile),
-            pl.BlockSpec((1, chunk), lambda i, c: (i, c)),
+            pl.BlockSpec((1, chunk, 1), tile),
             pl.BlockSpec((1, dh, dh), lambda i, c: (i, 0, 0)),
         ],
         out_specs=(
@@ -90,6 +90,6 @@ def wkv_pallas(r, k, v, w, beta, state, *, chunk: int = 128,
         out_shape=out_shapes,
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(r, k, v, w, beta, state)

@@ -25,6 +25,7 @@ from repro.config import TrainConfig, get_arch, scaled_down
 from repro.data.isa import stable_hash
 from repro.models import build_model
 from repro.train.trainer import Trainer
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.log import get_logger
 
 log = get_logger("repro.launch.train")
@@ -61,6 +62,7 @@ def main():
                     default="lm",
                     help="semanticbbv stages use the paper's objectives")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.preset == "smoke":

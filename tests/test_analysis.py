@@ -41,11 +41,10 @@ def test_collectives_counted():
     def f(x):
         return jax.lax.psum(x, "i")
 
-    import jax.experimental.shard_map as shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     devs = np.array(jax.devices()[:1])
     mesh = Mesh(devs.reshape(1), ("i",))
-    g = jax.jit(shard_map.shard_map(
+    g = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=P("i"), out_specs=P()))
     text = g.lower(jax.ShapeDtypeStruct((8,), jnp.float32)).compile().as_text()
     st = analyze_hlo(text)

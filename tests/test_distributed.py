@@ -12,15 +12,8 @@ from repro.distributed.sharding import (
     LOGICAL_RULES, logical_to_pspec, prune_pspec,
 )
 
-def _mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)            # jax >= 0.5 signature
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))  # jax 0.4.x signature
-
-
-MESH = _mesh((2, 16, 16), ("pod", "data", "model"))
-SINGLE = _mesh((16, 16), ("data", "model"))
+MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_logical_rules_basic():
@@ -70,7 +63,7 @@ def test_prune_always_valid(dim, axis):
 @pytest.mark.slow
 def test_dryrun_cell_subprocess():
     """Full 512-device lower+compile of one (arch, shape) cell."""
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
