@@ -40,6 +40,7 @@ from repro.core.crossprog import cpi_accuracy, speedup
 from repro.train.checkpoint import (
     latest_checkpoint, restore_checkpoint, save_checkpoint,
 )
+from repro.utils import tracing
 
 ASSIGN_IMPLS = ("auto", "reference", "numpy", "pallas", "pallas_interpret")
 
@@ -96,7 +97,9 @@ def assign_signatures(signatures: np.ndarray, centroids: np.ndarray,
         from repro.kernels.kmeans_assign.ops import kmeans_assign
         a, d2 = kmeans_assign(jnp.asarray(x), jnp.asarray(c),
                               interpret=(impl == "pallas_interpret"))
-    return np.asarray(a), np.asarray(d2)
+    a, d2 = np.asarray(a), np.asarray(d2)
+    tracing.add(h2d_bytes=x.nbytes + c.nbytes, d2h_bytes=a.nbytes + d2.nbytes)
+    return a, d2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,6 +180,10 @@ class KnowledgeBase:
         `device_matrix` (cluster-aligned compatible with "host"),
         optionally sharded over `mesh`'s data axes.
         """
+        with tracing.span("kb.build"):
+            return self._build(k, seed, impl, mesh)
+
+    def _build(self, k, seed, impl, mesh) -> "KnowledgeBase":
         if self.store.n_alive == 0:
             raise RuntimeError("cannot build a KnowledgeBase over an "
                                "empty SignatureStore (no live rows)")
@@ -250,24 +257,26 @@ class KnowledgeBase:
         """Fingerprint + CPI bookkeeping for a STORED program from its
         per-interval assignments (stamps the row count so streaming adds
         AND evictions trigger a re-attach on the next estimate)."""
-        rows = self.store.rows_for(program)
-        if rows.size == 0:
-            raise ValueError(
-                f"program {program!r} has no live rows in the store "
-                "(every interval was evicted) — cannot fingerprint")
-        weights = self.store.weights[rows]
-        cpis = self.store.cpis[rows]
-        f, wp = self._fingerprint(row_assign, weights)
-        self.fingerprints[program] = f
-        self.est_cpi[program] = float(
-            (f * self.rep_cpi.astype(np.float64)).sum())
-        if not np.isnan(np.asarray(cpis)).any():
-            self.true_cpi[program] = float(
-                (wp * np.asarray(cpis, np.float64)).sum())
-        else:
-            self.true_cpi[program] = None
-        self._attached_nrows[program] = len(rows)
-        return f
+        with tracing.span("kb.fingerprint") as s:
+            rows = self.store.rows_for(program)
+            s.add(rows=rows.size)
+            if rows.size == 0:
+                raise ValueError(
+                    f"program {program!r} has no live rows in the store "
+                    "(every interval was evicted) — cannot fingerprint")
+            weights = self.store.weights[rows]
+            cpis = self.store.cpis[rows]
+            f, wp = self._fingerprint(row_assign, weights)
+            self.fingerprints[program] = f
+            self.est_cpi[program] = float(
+                (f * self.rep_cpi.astype(np.float64)).sum())
+            if not np.isnan(np.asarray(cpis)).any():
+                self.true_cpi[program] = float(
+                    (wp * np.asarray(cpis, np.float64)).sum())
+            else:
+                self.true_cpi[program] = None
+            self._attached_nrows[program] = len(rows)
+            return f
 
     # ------------------------------------------------------------ queries
     def assign(self, signatures: np.ndarray
@@ -333,8 +342,10 @@ class KnowledgeBase:
         cached = self._row_assign_cache
         if cached is not None and cached[0] == self.store.version:
             return cached[1]
-        a, _ = assign_signatures(np.asarray(self.store.device_matrix),
-                                 self.archetypes, self.assign_impl)
+        with tracing.span("kb.assign_all") as s:
+            x = np.asarray(self.store.device_matrix)
+            s.add(rows_assigned=x.shape[0], d2h_bytes=x.nbytes)
+            a, _ = assign_signatures(x, self.archetypes, self.assign_impl)
         a = a[:len(self.store)]
         self._row_assign_cache = (self.store.version, a)
         return a
@@ -355,6 +366,12 @@ class KnowledgeBase:
 
         Returns the number of representatives that had to be re-pinned.
         """
+        with tracing.span("kb.apply_remap") as s:
+            repinned = self._apply_remap(remap)
+            s.add(repinned=repinned)
+            return repinned
+
+    def _apply_remap(self, remap: np.ndarray) -> int:
         self._require_built()
         remap = np.asarray(remap, np.int64)
         old = self.rep_global_idx

@@ -36,6 +36,7 @@ from repro.core.bbe import BBEConfig
 from repro.core.pipeline import PipelineConfig, SemanticBBVPipeline
 from repro.core.signature import SignatureConfig
 from repro.data.isa import BasicBlock
+from repro.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,9 +105,10 @@ class SemanticBBVService:
     def ingest_blocks(self, blocks: Sequence[BasicBlock]) -> int:
         """Stage-1 encode new basic blocks into the service's BBE table
         (LRU-cached in the pipeline); returns the table size."""
-        self.bbe_table.update(
-            self.pipe.encode_blocks(list(blocks), self.cfg.encode_batch))
-        return len(self.bbe_table)
+        with tracing.span("service.ingest_blocks"):
+            self.bbe_table.update(
+                self.pipe.encode_blocks(list(blocks), self.cfg.encode_batch))
+            return len(self.bbe_table)
 
     def ingest_intervals(self, program: str, intervals: Sequence,
                          cpis: Optional[Sequence[float]] = None
@@ -115,23 +117,26 @@ class SemanticBBVService:
         new store row indices. Interval instruction counts become the
         store weights (the weight-aware speedup + fingerprint norm).
         Blocks referenced by the intervals must have been ingested."""
-        sigs = self.pipe.interval_signatures(
-            list(intervals), self.bbe_table, self.cfg.signature_batch)
-        weights = [iv.num_instrs for iv in intervals]
-        return self.store.add(program, sigs, weights, cpis)
+        with tracing.span("service.ingest_intervals"):
+            sigs = self.pipe.interval_signatures(
+                list(intervals), self.bbe_table, self.cfg.signature_batch)
+            weights = [iv.num_instrs for iv in intervals]
+            return self.store.add(program, sigs, weights, cpis)
 
     # ------------------------------------------------------------ queries
     def build(self, k: Optional[int] = None,
               seed: Optional[int] = None) -> KnowledgeBase:
         """Universal clustering over everything ingested so far."""
-        return self.kb.build(
-            k=self.cfg.k if k is None else k,
-            seed=self.cfg.kmeans_seed if seed is None else seed)
+        with tracing.span("service.build"):
+            return self.kb.build(
+                k=self.cfg.k if k is None else k,
+                seed=self.cfg.kmeans_seed if seed is None else seed)
 
     def attach(self, program: str) -> np.ndarray:
         """Fingerprint an ingested-after-build program against the
         frozen archetypes (batched nearest-centroid, no re-clustering)."""
-        return self.kb.attach(program)
+        with tracing.span("service.attach"):
+            return self.kb.attach(program)
 
     def attach_many(self, programs,
                     cpis: Optional[Dict[str, Sequence[float]]] = None
@@ -148,45 +153,49 @@ class SemanticBBVService:
         against the frozen archetypes in ONE nearest-centroid call.
         Bit-identical fingerprints to sequential `attach`.
         """
-        if isinstance(programs, Mapping):
-            # fail BEFORE mutating the append-only store: a built check
-            # after ingest would leave orphan rows that a retry
-            # double-ingests
-            self.kb._require_built()
-            by_prog = {p: list(ivs) for p, ivs in programs.items()}
-            sigs = self.pipe.interval_signatures_many(
-                by_prog, self.bbe_table, self.cfg.signature_batch)
-            self.store.add_many([
-                (p, sigs[p], [iv.num_instrs for iv in ivs],
-                 None if cpis is None else cpis.get(p))
-                for p, ivs in by_prog.items()])
-            names = list(by_prog)
-        else:
-            names = list(programs)
-        return self.kb.attach_many(names)
+        with tracing.span("service.attach_many"):
+            if isinstance(programs, Mapping):
+                # fail BEFORE mutating the append-only store: a built
+                # check after ingest would leave orphan rows that a retry
+                # double-ingests
+                self.kb._require_built()
+                by_prog = {p: list(ivs) for p, ivs in programs.items()}
+                sigs = self.pipe.interval_signatures_many(
+                    by_prog, self.bbe_table, self.cfg.signature_batch)
+                self.store.add_many([
+                    (p, sigs[p], [iv.num_instrs for iv in ivs],
+                     None if cpis is None else cpis.get(p))
+                    for p, ivs in by_prog.items()])
+                names = list(by_prog)
+            else:
+                names = list(programs)
+            return self.kb.attach_many(names)
 
     def attach_intervals(self, program: str, intervals: Sequence
                          ) -> np.ndarray:
         """One-shot fingerprint WITHOUT ingesting into the store — a
         pure query that leaves no footprint in the knowledge base
         (use `ingest_intervals` + `estimate` for estimable programs)."""
-        sigs = self.pipe.interval_signatures(
-            list(intervals), self.bbe_table, self.cfg.signature_batch)
-        return self.kb.attach(program, signatures=sigs,
-                              weights=[iv.num_instrs for iv in intervals])
+        with tracing.span("service.attach_intervals"):
+            sigs = self.pipe.interval_signatures(
+                list(intervals), self.bbe_table, self.cfg.signature_batch)
+            return self.kb.attach(program, signatures=sigs,
+                                  weights=[iv.num_instrs for iv in intervals])
 
     def estimate(self, program: str) -> CPIEstimate:
-        est = self.kb.estimate(program)
-        # recency stamp AFTER the query (touch never bumps `version`,
-        # so the whole-store assignment cache stays warm)
-        self.store.touch(self.store.rows_for(program))
-        return est
+        with tracing.span("service.estimate"):
+            est = self.kb.estimate(program)
+            # recency stamp AFTER the query (touch never bumps `version`,
+            # so the whole-store assignment cache stays warm)
+            self.store.touch(self.store.rows_for(program))
+            return est
 
     # ---------------------------------------------------- store lifecycle
     def evict(self, program: str) -> int:
         """Tombstone every live interval row of `program` (reclaimed at
         the next `vacuum`); returns the number of rows evicted."""
-        return self.store.evict_program(program)
+        with tracing.span("service.evict"):
+            return self.store.evict_program(program)
 
     def vacuum(self, policy: Optional[EvictionPolicy] = None
                ) -> VacuumReport:
@@ -196,8 +205,9 @@ class SemanticBBVService:
         power of two), and re-pin the knowledge base through the row
         remap — estimates of untouched programs are bit-identical
         before/after (recorded archetype CPIs survive eviction)."""
-        return vacuum(self.store, self.kb,
-                      self.cfg.eviction if policy is None else policy)
+        with tracing.span("service.vacuum"):
+            return vacuum(self.store, self.kb,
+                          self.cfg.eviction if policy is None else policy)
 
     # -------------------------------------------------------- persistence
     def save(self, directory: str) -> str:

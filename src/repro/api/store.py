@@ -43,6 +43,7 @@ import numpy as np
 from repro.train.checkpoint import (
     latest_checkpoint, restore_checkpoint, save_checkpoint,
 )
+from repro.utils import tracing
 
 _MIN_CAPACITY = 64
 
@@ -191,14 +192,16 @@ class SignatureStore:
         accumulate. Signatures are stored as float32 — the dtype every
         query path already uses.
         """
-        sigs, w, c = self._validate(signatures, weights, cpis)
-        self._grow_to(self._n + sigs.shape[0])
-        rows = self._append(program, sigs, w, c)
-        self.version += 1
-        self._clock += 1
-        self._device = None
-        self._device_valid = None
-        return rows
+        with tracing.span("store.add") as s:
+            sigs, w, c = self._validate(signatures, weights, cpis)
+            self._grow_to(self._n + sigs.shape[0])
+            rows = self._append(program, sigs, w, c)
+            self.version += 1
+            self._clock += 1
+            self._device = None
+            self._device_valid = None
+            s.add(rows=rows.size)
+            return rows
 
     def add_many(self, items: Sequence[Tuple]) -> Dict[str, np.ndarray]:
         """Batched ingest: `items` is a sequence of (program, signatures[,
@@ -208,28 +211,31 @@ class SignatureStore:
         downstream whole-store assignment pass covers the entire batch.
         Returns {program: new row indices} (repeated programs accumulate).
         """
-        validated = []
-        for item in items:
-            program, sigs = item[0], item[1]
-            weights = item[2] if len(item) > 2 else None
-            cpis = item[3] if len(item) > 3 else None
-            validated.append((program, *self._validate(sigs, weights, cpis)))
-        if not validated:
-            return {}
-        # zero-row programs still register (matching `add`), so a later
-        # rows_for/attach sees them instead of raising KeyError
-        total = sum(v[1].shape[0] for v in validated)
-        self._grow_to(self._n + total)
-        out: Dict[str, np.ndarray] = {}
-        for program, sigs, w, c in validated:
-            rows = self._append(program, sigs, w, c)
-            out[program] = (rows if program not in out
-                            else np.concatenate([out[program], rows]))
-        self.version += 1
-        self._clock += 1
-        self._device = None
-        self._device_valid = None
-        return out
+        with tracing.span("store.add") as s:
+            validated = []
+            for item in items:
+                program, sigs = item[0], item[1]
+                weights = item[2] if len(item) > 2 else None
+                cpis = item[3] if len(item) > 3 else None
+                validated.append((program,
+                                  *self._validate(sigs, weights, cpis)))
+            if not validated:
+                return {}
+            # zero-row programs still register (matching `add`), so a
+            # later rows_for/attach sees them instead of raising KeyError
+            total = sum(v[1].shape[0] for v in validated)
+            self._grow_to(self._n + total)
+            out: Dict[str, np.ndarray] = {}
+            for program, sigs, w, c in validated:
+                rows = self._append(program, sigs, w, c)
+                out[program] = (rows if program not in out
+                                else np.concatenate([out[program], rows]))
+            self.version += 1
+            self._clock += 1
+            self._device = None
+            self._device_valid = None
+            s.add(rows=total)
+            return out
 
     # --------------------------------------------------------- lifecycle
     def touch(self, rows: np.ndarray) -> None:
@@ -283,6 +289,12 @@ class SignatureStore:
         that no longer exist. Row `uid`s survive compaction — persisted
         consumers (saved KnowledgeBases) re-resolve through them.
         """
+        with tracing.span("store.compact") as s:
+            remap = self._compact()
+            s.add(rows_before=remap.size, rows_after=self._n)
+            return remap
+
+    def _compact(self) -> np.ndarray:
         old_n = self._n
         keep = np.flatnonzero(self._alive[:old_n]).astype(np.int64)
         m = int(keep.size)
@@ -298,9 +310,10 @@ class SignatureStore:
             # re-upload and no per-row host loop
             idx = np.zeros(new_cap, np.int32)
             idx[:m] = keep
-            mask = (np.arange(new_cap) < m)
+            mask = (np.arange(new_cap) < m).astype(np.float32)[:, None]
             self._device = (jnp.take(self._device, jnp.asarray(idx), axis=0)
-                            * jnp.asarray(mask[:, None], jnp.float32))
+                            * jnp.asarray(mask))
+            tracing.add(h2d_bytes=idx.nbytes + mask.nbytes)
 
         sigs = np.zeros((new_cap, self.sig_dim), np.float32)
         sigs[:m] = self._sigs[keep]
@@ -435,7 +448,8 @@ class SignatureStore:
         compile per capacity level.
         """
         if self._device is None:
-            self._device = jnp.asarray(self._sigs)
+            with tracing.span("store.upload", h2d_bytes=self._sigs.nbytes):
+                self._device = jnp.asarray(self._sigs)
         return self._device
 
     @property
@@ -448,6 +462,7 @@ class SignatureStore:
             mask = np.zeros(self.capacity, np.float32)
             mask[:self._n] = self._alive[:self._n]
             self._device_valid = jnp.asarray(mask)
+            tracing.add(h2d_bytes=mask.nbytes)
         return self._device_valid
 
     # ------------------------------------------------------- persistence

@@ -33,6 +33,7 @@ from repro.core import bbe as bbe_mod
 from repro.core import signature as sig_mod
 from repro.core.tokenizer import MultiDimTokenizer, default_tokenizer
 from repro.data.isa import BasicBlock
+from repro.utils import tracing
 
 _BBE_CACHE_SIZE = 1 << 16
 
@@ -239,12 +240,15 @@ class SemanticBBVPipeline:
         outs = []
         n = tokens.shape[0]
         for i in range(0, n, batch):
-            chunk = tokens[i:i + batch]
-            got = chunk.shape[0]
-            if got < batch:
-                chunk = np.pad(chunk, ((0, batch - got), (0, 0), (0, 0)))
-            out = np.asarray(fn(params=self.bbe_params,
-                                tokens=jnp.asarray(chunk)))
+            with tracing.span("pipeline.encode") as s:
+                chunk = tokens[i:i + batch]
+                got = chunk.shape[0]
+                if got < batch:
+                    chunk = np.pad(chunk, ((0, batch - got), (0, 0), (0, 0)))
+                out = np.asarray(fn(params=self.bbe_params,
+                                    tokens=jnp.asarray(chunk)))
+                s.add(h2d_bytes=chunk.nbytes, d2h_bytes=out.nbytes,
+                      rows=got, padded_rows=batch - got)
             outs.append(out[:got])
         if not outs:
             return np.zeros((0, self.bbe_cfg.bbe_dim), np.float32)
@@ -337,6 +341,7 @@ class SemanticBBVPipeline:
             index = BBEIndex(bbe_table)
             if index.num_rows:
                 matrix = jnp.asarray(index.ext)
+                tracing.add(h2d_bytes=index.ext.nbytes)
             else:
                 matrix = jnp.zeros((1, self.sig_cfg.bbe_dim), jnp.float32)
             state.update(table=bbe_table, n=len(bbe_table), index=index,
@@ -356,21 +361,27 @@ class SemanticBBVPipeline:
         index, matrix = self._table_index(bbe_table)
         sigs, cpis = [], []
         for i in range(0, len(intervals), batch):
-            row_ids, freqs, mask = self._batch_set_ids(
-                intervals[i:i + batch], index)
-            got = row_ids.shape[0]
-            if got < batch:
-                pad = batch - got
-                row_ids = np.pad(row_ids, ((0, pad), (0, 0)),
-                                 constant_values=index.sentinel)
-                freqs = np.pad(freqs, ((0, pad), (0, 0)))
-                mask = np.pad(mask, ((0, pad), (0, 0)))
-            sig, logcpi = fn(params=self.sig_params, matrix=matrix,
-                             row_ids=jnp.asarray(row_ids),
-                             freqs=jnp.asarray(freqs),
-                             mask=jnp.asarray(mask))
-            sigs.append(np.asarray(sig)[:got])
-            cpis.append(np.asarray(logcpi)[:got])
+            with tracing.span("pipeline.set_assembly") as s:
+                row_ids, freqs, mask = self._batch_set_ids(
+                    intervals[i:i + batch], index)
+                got = row_ids.shape[0]
+                if got < batch:
+                    pad = batch - got
+                    row_ids = np.pad(row_ids, ((0, pad), (0, 0)),
+                                     constant_values=index.sentinel)
+                    freqs = np.pad(freqs, ((0, pad), (0, 0)))
+                    mask = np.pad(mask, ((0, pad), (0, 0)))
+                s.add(rows=got, padded_rows=batch - got)
+            with tracing.span("pipeline.stage2") as s:
+                sig, logcpi = fn(params=self.sig_params, matrix=matrix,
+                                 row_ids=jnp.asarray(row_ids),
+                                 freqs=jnp.asarray(freqs),
+                                 mask=jnp.asarray(mask))
+                sig, logcpi = np.asarray(sig), np.asarray(logcpi)
+                s.add(h2d_bytes=row_ids.nbytes + freqs.nbytes + mask.nbytes,
+                      d2h_bytes=sig.nbytes + logcpi.nbytes)
+            sigs.append(sig[:got])
+            cpis.append(logcpi[:got])
         if not sigs:
             return (np.zeros((0, self.sig_cfg.sig_dim), np.float32),
                     np.zeros((0,), np.float32))
