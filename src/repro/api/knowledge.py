@@ -21,9 +21,13 @@ Assignment backend is selectable per base (`assign_impl`):
   "pallas_interpret"  same kernel under the interpreter (CPU parity)
   "auto"              "pallas" on TPU, "reference" elsewhere
 
-Query batches are padded to the store's power-of-two capacity (stored
-programs) or the next power of two (ad-hoc signatures), so every
-backend sees O(log N) shapes — one compile per capacity level.
+Stored rows are labelled once: the base keeps every row's nearest
+archetype and follows the store through appends, evictions and
+compactions, so a query assigns only the rows added since the last one,
+padded to the next power of two like ad-hoc signatures. Only when the
+cache cannot follow the store (a new build, a compaction whose remap it
+never saw) is the whole padded `device_matrix` assigned, in place.
+Every backend sees O(log N) shapes — one compile per power of two.
 """
 from __future__ import annotations
 
@@ -81,11 +85,19 @@ def assign_signatures(signatures: np.ndarray, centroids: np.ndarray,
     The impl switch mirrors the set-attention kernels: a numpy oracle,
     the jnp reference, and the Pallas `kmeans_assign` kernel (compiled
     or interpreted) — all parity-tested against each other.
+
+    `signatures` may be a float32 device array (the store's resident
+    `device_matrix`): the jnp and Pallas backends read it where it is,
+    with no host copy and no upload.
     """
     impl = resolve_assign_impl(impl)
-    x = np.asarray(signatures, np.float32)
+    on_device = isinstance(signatures, jax.Array)
+    x = signatures if on_device else np.asarray(signatures, np.float32)
     c = np.asarray(centroids, np.float32)
     if impl == "numpy":
+        if on_device:
+            x = np.asarray(x, np.float32)
+            tracing.add(d2h_bytes=x.nbytes)
         d2 = (np.sum(x * x, -1, keepdims=True) - 2.0 * (x @ c.T)
               + np.sum(c * c, -1)[None, :])
         return d2.argmin(-1).astype(np.int32), d2.min(-1).astype(np.float32)
@@ -98,7 +110,8 @@ def assign_signatures(signatures: np.ndarray, centroids: np.ndarray,
         a, d2 = kmeans_assign(jnp.asarray(x), jnp.asarray(c),
                               interpret=(impl == "pallas_interpret"))
     a, d2 = np.asarray(a), np.asarray(d2)
-    tracing.add(h2d_bytes=x.nbytes + c.nbytes, d2h_bytes=a.nbytes + d2.nbytes)
+    tracing.add(h2d_bytes=(0 if on_device else x.nbytes) + c.nbytes,
+                d2h_bytes=a.nbytes + d2.nbytes)
     return a, d2
 
 
@@ -147,8 +160,9 @@ class KnowledgeBase:
         self.est_cpi: Dict[str, float] = {}
         self.true_cpi: Dict[str, Optional[float]] = {}
         self._built_version: Optional[int] = None
-        # (store.version, per-row assignment) for the whole-store query
-        self._row_assign_cache: Optional[Tuple[int, np.ndarray]] = None
+        # (store, store.row_epoch, labels of row slots [0, len(labels)))
+        self._row_assign_cache: Optional[
+            Tuple[SignatureStore, int, np.ndarray]] = None
         # rows_for(p) size when p was last fingerprinted — detects
         # streaming adds to an already-attached program
         self._attached_nrows: Dict[str, int] = {}
@@ -299,11 +313,11 @@ class KnowledgeBase:
         """Fingerprint a new, unseen program against the frozen
         archetypes; returns the (k,) fingerprint.
 
-        With no explicit `signatures`, the program's rows are read from
-        the store through the static-capacity `device_matrix` — the
-        whole store is assigned in ONE batched kernel call (cached per
-        store version), so attaching many late-ingested programs costs
-        one device pass, not one per program.
+        With no explicit `signatures`, the program's labels come from
+        the base's per-row label cache (`_all_row_assign`): only the
+        store rows added since the last query are assigned, in ONE
+        batched kernel call, so attaching many late-ingested programs
+        costs one device pass over their new rows, not one per program.
 
         With explicit `signatures` this is a PURE QUERY: nothing is
         recorded into the knowledge base (no est_cpi / avg_accuracy /
@@ -324,12 +338,12 @@ class KnowledgeBase:
                     ) -> Dict[str, np.ndarray]:
         """Fingerprint MANY stored programs in one batched device pass.
 
-        The whole padded store is assigned against the frozen archetypes
-        once (`_all_row_assign`, one kernel call at the store's static
-        capacity shape); every requested program is then recorded from
-        its slice of that shared assignment. Bit-identical to calling
-        `attach(p)` per program, without N cache lookups racing store
-        versions — the multi-tenant ingest-then-attach path.
+        The rows not yet labelled — every program ingested since the
+        last query — are assigned against the frozen archetypes in one
+        kernel call (`_all_row_assign`); every requested program is then
+        recorded from its slice of the shared per-row labels.
+        Bit-identical to calling `attach(p)` per program — the
+        multi-tenant ingest-then-attach path.
         """
         self._require_built()
         row_assign = self._all_row_assign()
@@ -337,27 +351,52 @@ class KnowledgeBase:
                 for p in programs}
 
     def _all_row_assign(self) -> np.ndarray:
-        """Assignment of every valid store row, computed over the padded
-        device-resident matrix (static shape per capacity level)."""
+        """Nearest-archetype label of every store row slot.
+
+        A row's signature never changes after `add` and the archetypes
+        are frozen between builds, so labels are kept across calls and
+        only the rows appended since the last call are assigned: read
+        from the store's host buffer and padded to the next power of two
+        (`assign`). Evictions leave the labels as they are (`rows_for`
+        never returns a tombstoned row) and `apply_remap` carries them
+        through a compaction. When the rows moved in a way the cache did
+        not follow — another store, or a compaction whose remap was never
+        applied (`store.row_epoch`) — every row is assigned in one pass
+        over the resident `device_matrix`.
+        """
+        store = self.store
+        n = len(store)
         cached = self._row_assign_cache
-        if cached is not None and cached[0] == self.store.version:
-            return cached[1]
+        follows = (cached is not None and cached[0] is store
+                   and cached[1] == store.row_epoch)
+        n_cached = len(cached[2]) if follows else 0
+        if follows and n_cached == n:
+            return cached[2]
         with tracing.span("kb.assign_all") as s:
-            x = np.asarray(self.store.device_matrix)
-            s.add(rows_assigned=x.shape[0], d2h_bytes=x.nbytes)
-            a, _ = assign_signatures(x, self.archetypes, self.assign_impl)
-        a = a[:len(self.store)]
-        self._row_assign_cache = (self.store.version, a)
-        return a
+            if follows:
+                m = n - n_cached
+                a, _ = self.assign(store.signatures[n_cached:])
+                labels = np.concatenate([cached[2], a])
+                s.add(rows_assigned=m, padded_rows=_capacity_for(m, 1) - m,
+                      rows_cached=n_cached, full_pass=0)
+            else:
+                a, _ = assign_signatures(store.device_matrix,
+                                         self.archetypes, self.assign_impl)
+                labels = a[:n]
+                s.add(rows_assigned=n, padded_rows=store.capacity - n,
+                      rows_cached=0, full_pass=1)
+        self._row_assign_cache = (store, store.row_epoch, labels)
+        return labels
 
     # ----------------------------------------------------- store lifecycle
     def apply_remap(self, remap: np.ndarray) -> int:
         """Consume a `SignatureStore.compact()` old->new row remap so the
         knowledge base stays valid across compaction: representative rows
         move to their new positions, fingerprints of programs the
-        compaction dropped entirely are pruned, and representatives whose
-        rows were evicted are re-pinned to the nearest live member of
-        their archetype via ONE extra whole-store assignment pass.
+        compaction dropped entirely are pruned, the per-row label cache
+        is gathered through the remap, and representatives whose rows
+        were evicted are re-pinned to the nearest live member of their
+        archetype from those labels.
 
         Recorded `rep_cpi`/`rep_weight` are KEPT even when re-pinning:
         they are the results of the one-time archetype simulation, which
@@ -378,7 +417,7 @@ class KnowledgeBase:
         safe = np.clip(old, 0, max(remap.shape[0] - 1, 0))
         self.rep_global_idx = np.where(
             (old >= 0) & (old < remap.shape[0]), remap[safe], -1)
-        self._row_assign_cache = None
+        self._carry_row_assign(remap)
         for p in list(self.fingerprints):
             if p not in self.store:        # compaction dropped the program
                 del self.fingerprints[p]
@@ -387,11 +426,26 @@ class KnowledgeBase:
                 self._attached_nrows.pop(p, None)
         return self._repin_dead_reps()
 
+    def _carry_row_assign(self, remap: np.ndarray) -> None:
+        """Move the per-row labels through the remap of the store's
+        latest compaction (order-preserving, so the surviving labels keep
+        their order: new[remap[keep]] = old[keep]). A cache from before
+        an earlier compaction, or of another store, is dropped."""
+        cached, self._row_assign_cache = self._row_assign_cache, None
+        if cached is None or cached[0] is not self.store:
+            return
+        store, epoch, labels = cached
+        if epoch == store.row_epoch:              # the compaction was a no-op
+            self._row_assign_cache = cached
+        elif epoch == store.row_epoch - 1 and remap.shape[0] >= len(labels):
+            kept = labels[remap[:len(labels)] >= 0]
+            self._row_assign_cache = (store, store.row_epoch, kept)
+
     def _repin_dead_reps(self) -> int:
         """Re-pin every representative whose store row is gone (idx -1)
-        to the nearest LIVE member of its archetype: one whole-store
-        assignment pass (`_all_row_assign`) + one segment-reduce
-        (`representatives`) shared by all dead reps."""
+        to the nearest LIVE member of its archetype: the per-row labels
+        (`_all_row_assign`) + one segment-reduce (`representatives`)
+        shared by all dead reps."""
         dead = np.flatnonzero(self.rep_global_idx < 0)
         if dead.size == 0:
             return 0

@@ -149,8 +149,9 @@ class SemanticBBVService:
         signature generation is pipelined across ALL programs in one
         padded batch stream (`interval_signatures_many`), the rows land
         in the store via one `add_many` (single capacity growth, single
-        version bump), and the whole padded store is then assigned
-        against the frozen archetypes in ONE nearest-centroid call.
+        version bump), and their new rows — all programs' together — are
+        then assigned against the frozen archetypes in ONE
+        nearest-centroid call.
         Bit-identical fingerprints to sequential `attach`.
         """
         with tracing.span("service.attach_many"):
@@ -185,8 +186,8 @@ class SemanticBBVService:
     def estimate(self, program: str) -> CPIEstimate:
         with tracing.span("service.estimate"):
             est = self.kb.estimate(program)
-            # recency stamp AFTER the query (touch never bumps `version`,
-            # so the whole-store assignment cache stays warm)
+            # recency stamp AFTER the query (pure metadata: the per-row
+            # label cache is untouched)
             self.store.touch(self.store.rows_for(program))
             return est
 

@@ -18,7 +18,9 @@ inference path's `BBEIndex`:
   survives `compact()` — the handle persisted artifacts (KnowledgeBase
   representatives) use to stay valid across the store's whole lifetime.
   `version` increments per mutation (`add`/`evict`/`compact`), so
-  consumers can cache derived state keyed on it.
+  consumers can cache derived state keyed on it; `row_epoch` increments
+  only when rows move (`compact`), so per-row state survives appends and
+  evictions and is carried through the remap of the latest compaction.
 
 LIFECYCLE. Long-running serving ingests forever, so the store is no
 longer grow-only: `evict(rows)` tombstones rows (a host bitmap folded
@@ -76,6 +78,10 @@ class SignatureStore:
         self.sig_dim = int(sig_dim)
         self.min_capacity = int(min_capacity)
         self.version = 0
+        # bumped by each compaction that moves rows: per-row state
+        # labelled at one epoch is valid at the next only through the
+        # remap that compaction returned
+        self.row_epoch = 0
         self._n = 0
         self._n_dead = 0
         self._clock = 0          # logical time: one tick per add/touch
@@ -208,7 +214,7 @@ class SignatureStore:
         weights[, cpis]]) tuples. All inputs are validated up front,
         capacity grows ONCE for the total row count (one buffer copy
         instead of one per doubling), and `version` bumps once — so one
-        downstream whole-store assignment pass covers the entire batch.
+        downstream assignment pass over the new rows covers the batch.
         Returns {program: new row indices} (repeated programs accumulate).
         """
         with tracing.span("store.add") as s:
@@ -345,6 +351,7 @@ class SignatureStore:
         self._n = m
         self._n_dead = 0
         self.version += 1
+        self.row_epoch += 1
         self._device_valid = None
         return remap
 

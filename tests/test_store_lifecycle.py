@@ -7,9 +7,11 @@ import numpy as np
 import pytest
 
 from repro.api import (
-    EvictionPolicy, KnowledgeBase, SignatureStore, select_victims, vacuum,
+    EvictionPolicy, KnowledgeBase, SignatureStore, assign_signatures,
+    select_victims, vacuum,
 )
 from repro.api.store import _capacity_for
+from repro.utils import tracing
 
 
 def _blob_program(seed, centers, n_per=25, noise=0.05):
@@ -500,3 +502,113 @@ def test_vacuum_compact_threshold(blob_centers):
     report = vacuum(store, None, EvictionPolicy())
     assert report.evicted == 0 and not report.compacted
     assert store.version == v
+
+
+# ------------------------------------------------- per-row label cache
+
+def _assign_all_spans(since):
+    return [s.counts for s in tracing.recent()
+            if s.name == "kb.assign_all" and s.span_id > since]
+
+
+def _mark():
+    with tracing.span("test.mark") as m:
+        pass
+    return m.span_id
+
+
+@pytest.mark.parametrize("impl", ["numpy", "pallas_interpret", "reference"])
+def test_incremental_row_assign_matches_whole_store_pass(blob_centers, impl):
+    """After every step of a serving sequence the cached labels equal a
+    fresh whole-store pass with the same backend (under "reference",
+    except where a row's two nearest archetypes lie within 1e-6): only
+    new rows are assigned, evictions and an applied remap assign nothing,
+    and a compaction the base never saw forces one whole-store pass."""
+    store = _filled_store(blob_centers, ["A", "B"])        # 150 rows
+    kb = KnowledgeBase(store, assign_impl=impl).build(k=3, seed=0)
+
+    def check(**expect):
+        since = _mark()
+        labels = kb._all_row_assign().copy()
+        spans = _assign_all_spans(since)
+        if expect:
+            assert len(spans) == 1
+            assert {k: spans[0][k] for k in expect} == expect
+        else:
+            assert spans == []
+        full, _ = assign_signatures(store.device_matrix, kb.archetypes, impl)
+        full = full[:len(store)]
+        assert labels.shape == full.shape
+        if impl == "reference":
+            x = store.signatures.astype(np.float64)
+            c = kb.archetypes.astype(np.float64)
+            d2 = np.sort(((x[:, None] - c[None]) ** 2).sum(-1), -1)
+            tied = d2[:, 1] - d2[:, 0] < 1e-6
+            np.testing.assert_array_equal(labels[~tied], full[~tied])
+        else:
+            np.testing.assert_array_equal(labels, full)
+
+    check(full_pass=1, rows_assigned=150, padded_rows=256 - 150,
+          rows_cached=0)
+    sP, cP = _blob_program(30, blob_centers)
+    store.add("P", sP[:20], cpis=cP[:20])
+    check(full_pass=0, rows_assigned=20, padded_rows=32 - 20,
+          rows_cached=150)
+    items = [(n, *_blob_program(31 + j, blob_centers)[:1])
+             for j, n in enumerate(["Q", "R"])]
+    store.add_many([(n, s[:30]) for n, s in items])
+    check(full_pass=0, rows_assigned=60, padded_rows=64 - 60,
+          rows_cached=170)
+    store.evict(store.rows_for("A")[::2])
+    store.evict_program("Q")
+    check()                                  # nothing new to label
+    kb.apply_remap(store.compact())
+    assert len(store) == 230 - 38 - 30
+    check()                                  # carried through the remap
+    store.add("P", sP[20:], cpis=cP[20:])
+    check(full_pass=0, rows_assigned=55, padded_rows=64 - 55,
+          rows_cached=162)
+    store.evict_program("R")
+    store.compact()                          # remap never applied
+    check(full_pass=1, rows_assigned=187, padded_rows=256 - 187,
+          rows_cached=0)
+    # the remap of a later compaction, as long as the cache, cannot carry
+    # labels across one the base missed (P's rows lie after every
+    # representative's row)
+    store.evict(store.rows_for("P")[:5])
+    store.compact()
+    store.add("S", _blob_program(33, blob_centers)[0][:10])
+    store.evict(store.rows_for("P")[:5])
+    assert kb.apply_remap(store.compact()) == 0
+    check(full_pass=1, rows_assigned=187, padded_rows=256 - 187,
+          rows_cached=0)
+
+
+def test_build_load_and_store_swap_reset_row_assign_cache(tmp_path,
+                                                          blob_centers):
+    """New archetypes (`build`, `KnowledgeBase.load`) or another store
+    under the base start from a whole-store pass."""
+    store = _filled_store(blob_centers, ["A", "B"])
+    kb = KnowledgeBase(store).build(k=3, seed=0)
+    sP, cP = _blob_program(50, blob_centers)
+    store.add("P", sP, cpis=cP)
+    kb.attach("P")
+    assert kb._row_assign_cache is not None
+    kb.build(k=3, seed=1)
+    assert kb._row_assign_cache is None
+    kb.attach("P")
+    kb.save(str(tmp_path / "kb"))
+    kb2 = KnowledgeBase.load(str(tmp_path / "kb"), store)
+    assert kb2._row_assign_cache is None
+    since = _mark()
+    np.testing.assert_array_equal(kb2._all_row_assign(),
+                                  kb._all_row_assign())
+    assert [c["full_pass"] for c in _assign_all_spans(since)] == [1]
+
+    other = _filled_store(blob_centers, ["A", "B"])
+    other.add("P", sP, cpis=cP)
+    kb.store = other                          # same length, new store
+    since = _mark()
+    np.testing.assert_array_equal(kb._all_row_assign(),
+                                  kb2._all_row_assign())
+    assert [c["full_pass"] for c in _assign_all_spans(since)] == [1]

@@ -76,6 +76,15 @@ def test_kmeans_assign_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_kmeans_assign_tail_compiles(one_chip):
+    """The shape an attach request assigns: its 1,000 new rows, padded
+    to the next power of two."""
+    text = _compiled_text(
+        lambda x, c: kmeans_assign(x, c, interpret=False),
+        _spec(one_chip, (1024, SIG_DIM)), _spec(one_chip, (K, SIG_DIM)))
+    assert "tpu_custom_call" in text
+
+
 def test_kmeans_update_compiles(one_chip):
     text = _compiled_text(
         lambda x, c, v: kmeans_update(x, c, v, interpret=False),

@@ -133,9 +133,9 @@ def test_no_program_span_is_named_like_a_benchmark_span():
 
 @pytest.fixture(scope="module")
 def tiny_run():
-    """A tiny service built over two programs; a third is ingested,
-    estimated, evicted and vacuumed, and the spans of those four calls
-    are returned with the service."""
+    """A tiny service built over two programs; a third is ingested and
+    estimated in two chunks, evicted and vacuumed, and the spans of those
+    six calls are returned with the service."""
     from repro.api import SemanticBBVService, ServiceConfig
     from repro.core.bbe import BBEConfig
     from repro.core.signature import SignatureConfig
@@ -144,7 +144,8 @@ def tiny_run():
     from repro.data.trace import block_table, trace_program
     progs = spec_programs("int")[:3]
     bt = block_table(progs)
-    ivs = {p.name: trace_program(p, 16) for p in progs}
+    ivs = {p.name: trace_program(p, 20 if i == 2 else 16)
+           for i, p in enumerate(progs)}
     cpis = {n: [interval_cpi(iv, bt, INORDER_CPU) for iv in v]
             for n, v in ivs.items()}
     cfg = ServiceConfig(
@@ -162,6 +163,8 @@ def tiny_run():
     with tracing.span("test.mark") as mark:
         pass
     svc.ingest_intervals(new, ivs[new][:10], cpis=cpis[new][:10])
+    svc.estimate(new)
+    svc.ingest_intervals(new, ivs[new][10:], cpis=cpis[new][10:])
     svc.estimate(new)
     svc.evict(new)
     svc.vacuum()
@@ -183,11 +186,15 @@ def _tree(spans):
 
 def test_service_span_tree(tiny_run):
     _, spans = tiny_run
+    ingest = ("service.ingest_intervals", [("pipeline.set_assembly", []),
+                                           ("pipeline.stage2", []),
+                                           ("store.add", [])])
     assert _tree(spans) == [
-        ("service.ingest_intervals", [("pipeline.set_assembly", []),
-                                      ("pipeline.stage2", []),
-                                      ("store.add", [])]),
+        ingest,
         ("service.estimate", [("kb.assign_all", [("store.upload", [])]),
+                              ("kb.fingerprint", [])]),
+        ingest,
+        ("service.estimate", [("kb.assign_all", []),
                               ("kb.fingerprint", [])]),
         ("service.evict", []),
         ("service.vacuum", [("store.compact", []),
@@ -198,30 +205,44 @@ def test_service_span_tree(tiny_run):
 
 
 def test_service_counts_by_hand(tiny_run):
-    """Store: 2 x 16 rows + 10 new rows of sig_dim 16 at capacity 64;
-    Stage 2: one batch of 32 sets of 48; k = 3 archetypes."""
+    """Store: 2 x 16 rows + two chunks of 10 new rows of sig_dim 16 at
+    capacity 64; Stage 2: one batch of 32 sets of 48 per chunk; k = 3
+    archetypes. The first estimate after build assigns the whole store
+    in place (one upload, nothing downloaded but the labels); the second
+    assigns only its chunk, padded to 16, from the host."""
     svc, spans = tiny_run
-    c = {s.name: s.counts for s in spans}
-    batch, n_set, sig, cap, k = 32, 48, 16, 64, 3
-    assert c["pipeline.set_assembly"] == {"rows": 10,
-                                          "padded_rows": batch - 10}
+    c = {}
+    for s in spans:
+        c.setdefault(s.name, []).append(s.counts)
+    batch, n_set, sig, cap, k, tail = 32, 48, 16, 64, 3, 16
+    assert c["pipeline.set_assembly"] == 2 * [{"rows": 10,
+                                               "padded_rows": batch - 10}]
     stage2_up = batch * n_set * (4 + 4 + 1)     # row ids, freqs, mask
     stage2_down = batch * (sig + 1) * 4         # signatures, log CPI
-    assert c["pipeline.stage2"] == {"h2d_bytes": stage2_up,
-                                    "d2h_bytes": stage2_down}
-    assert c["store.add"] == {"rows": 10}
+    assert c["pipeline.stage2"] == 2 * [{"h2d_bytes": stage2_up,
+                                         "d2h_bytes": stage2_down}]
+    assert c["store.add"] == 2 * [{"rows": 10}]
     store = cap * sig * 4
-    assert c["store.upload"] == {"h2d_bytes": store}
-    assert c["kb.assign_all"] == {
-        "rows_assigned": cap,
-        "h2d_bytes": store + k * sig * 4,          # store, archetypes
-        "d2h_bytes": store + 2 * cap * 4}          # store, assign, dist
-    assert c["kb.fingerprint"] == {"rows": 10}
-    assert c["store.compact"] == {"rows_before": 42, "rows_after": 32,
-                                  "h2d_bytes": 2 * 32 * 4}  # index, mask
-    assert c["kb.apply_remap"] == {"repinned": 0}
+    assert c["store.upload"] == [{"h2d_bytes": store}]
+    assert c["kb.assign_all"] == [
+        {"rows_assigned": 42, "padded_rows": cap - 42, "rows_cached": 0,
+         "full_pass": 1,
+         "h2d_bytes": k * sig * 4,                  # archetypes
+         "d2h_bytes": 2 * cap * 4},                 # assign, dist
+        {"rows_assigned": 10, "padded_rows": tail - 10, "rows_cached": 42,
+         "full_pass": 0,
+         "h2d_bytes": tail * sig * 4 + k * sig * 4,  # chunk, archetypes
+         "d2h_bytes": 2 * tail * 4}]
+    assert c["kb.fingerprint"] == [{"rows": 10}, {"rows": 20}]
+    # no device matrix is resident after the second add: host compaction
+    assert c["store.compact"] == [{"rows_before": 52, "rows_after": 32}]
+    assert c["kb.apply_remap"] == [{"repinned": 0}]
     assert svc.store.capacity == 32
-    h2d = sum(s.get("h2d_bytes", 0) for s in c.values())
-    d2h = sum(s.get("d2h_bytes", 0) for s in c.values())
-    assert h2d == stage2_up + 2 * store + k * sig * 4 + 2 * 32 * 4
-    assert d2h == stage2_down + store + 2 * cap * 4
+    _, epoch, labels = svc.kb._row_assign_cache  # carried through the remap
+    assert epoch == svc.store.row_epoch and len(labels) == 32
+    counts = [x for v in c.values() for x in v]
+    h2d = sum(x.get("h2d_bytes", 0) for x in counts)
+    d2h = sum(x.get("d2h_bytes", 0) for x in counts)
+    assert h2d == (2 * stage2_up + store + 2 * k * sig * 4
+                   + tail * sig * 4)
+    assert d2h == 2 * stage2_down + 2 * cap * 4 + 2 * tail * 4
