@@ -155,7 +155,7 @@ class KnowledgeBase:
         self.rep_uid = np.zeros(0, np.int64)           # compaction-stable
         self.rep_program: List[str] = []
         self.rep_cpi = np.zeros(0, np.float32)
-        self.rep_weight = np.zeros(0, np.float32)
+        self.rep_weight = np.zeros(0, np.float64)
         self.fingerprints: Dict[str, np.ndarray] = {}
         self.est_cpi: Dict[str, float] = {}
         self.true_cpi: Dict[str, Optional[float]] = {}
@@ -240,7 +240,7 @@ class KnowledgeBase:
         self.rep_uid = np.asarray(self.store.uids[reps], np.int64)
         self.rep_program = [self.store.program_of_row[i] for i in reps]
         self.rep_cpi = self.store.cpis[reps].astype(np.float32)
-        self.rep_weight = self.store.weights[reps].astype(np.float32)
+        self.rep_weight = self.store.weights[reps].astype(np.float64)
         if np.isnan(self.rep_cpi).any():
             raise ValueError(
                 "representative intervals lack ground-truth CPI; ingest "
@@ -478,7 +478,7 @@ class KnowledgeBase:
         f = self.fingerprints[program]
         est = self.est_cpi[program]
         true = self.true_cpi[program]
-        sim_w = float(self.rep_weight.astype(np.float64).sum())
+        sim_w = float(self.rep_weight.sum())
         total_w = self.store.total_weight
         return CPIEstimate(
             program=program, est_cpi=est, true_cpi=true,
@@ -540,7 +540,7 @@ class KnowledgeBase:
         kb.seed = int(meta["seed"])
         kb.archetypes = np.asarray(tree["archetypes"], np.float32)
         kb.rep_cpi = np.asarray(tree["rep_cpi"], np.float32)
-        kb.rep_weight = np.asarray(tree["rep_weight"], np.float32)
+        kb.rep_weight = np.asarray(tree["rep_weight"], np.float64)
         kb.rep_global_idx = np.asarray(tree["rep_global_idx"], np.int64)
         kb.rep_program = list(meta["rep_program"])
         if "rep_uid" in tree:

@@ -63,6 +63,8 @@ class SignatureStore:
     Rows carry (signature (d,), weight, cpi, program). `weight` is the
     interval's instruction count (uniform 1.0 when unknown) — it drives
     both fingerprint normalization and the weight-aware speedup metric.
+    It is float64, exact for every integer count below 2**53: a region
+    of 8 threads at 10 M instructions each is past float32's 2**24.
     `cpi` is the ground-truth per-interval CPI, NaN when unknown: the
     knowledge base only ever consults it at the k representative
     intervals (the paper's "simulate only the archetypes") and for
@@ -88,7 +90,7 @@ class SignatureStore:
         self._next_uid = 0
         cap = _capacity_for(0, self.min_capacity)
         self._sigs = np.zeros((cap, self.sig_dim), np.float32)
-        self._weights = np.zeros((cap,), np.float32)
+        self._weights = np.zeros((cap,), np.float64)
         self._cpis = np.full((cap,), np.nan, np.float32)
         self._alive = np.zeros((cap,), bool)
         self._uids = np.zeros((cap,), np.int64)
@@ -139,7 +141,7 @@ class SignatureStore:
             return
         sigs = np.zeros((cap, self.sig_dim), np.float32)
         sigs[:self._n] = self._sigs[:self._n]
-        weights = np.zeros((cap,), np.float32)
+        weights = np.zeros((cap,), np.float64)
         weights[:self._n] = self._weights[:self._n]
         cpis = np.full((cap,), np.nan, np.float32)
         cpis[:self._n] = self._cpis[:self._n]
@@ -163,8 +165,8 @@ class SignatureStore:
             raise ValueError(
                 f"signatures must be (N, {self.sig_dim}), got {sigs.shape}")
         b = sigs.shape[0]
-        w = (np.ones(b, np.float32) if weights is None
-             else np.asarray(weights, np.float32))
+        w = (np.ones(b, np.float64) if weights is None
+             else np.asarray(weights, np.float64))
         c = (np.full(b, np.nan, np.float32) if cpis is None
              else np.asarray(cpis, np.float32))
         if w.shape != (b,) or c.shape != (b,):
@@ -323,7 +325,7 @@ class SignatureStore:
 
         sigs = np.zeros((new_cap, self.sig_dim), np.float32)
         sigs[:m] = self._sigs[keep]
-        weights = np.zeros((new_cap,), np.float32)
+        weights = np.zeros((new_cap,), np.float64)
         weights[:m] = self._weights[keep]
         cpis = np.full((new_cap,), np.nan, np.float32)
         cpis[:m] = self._cpis[keep]
@@ -520,7 +522,7 @@ class SignatureStore:
         n = sigs.shape[0]
         store._grow_to(n)
         store._sigs[:n] = sigs
-        store._weights[:n] = np.asarray(tree["weights"], np.float32)
+        store._weights[:n] = np.asarray(tree["weights"], np.float64)
         store._cpis[:n] = np.asarray(tree["cpis"], np.float32)
         store._alive[:n] = (np.asarray(tree["alive"], bool)
                             if "alive" in tree else True)

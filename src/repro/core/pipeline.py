@@ -115,12 +115,31 @@ def _topk_order(seg: np.ndarray, cnts: np.ndarray) -> np.ndarray:
     return np.lexsort((-cnts, seg))
 
 
+def _set_entries(items):
+    """Every item's candidate set entries (`set_entries()`) as flat
+    arrays, item by item -> (lens (B,), bids, counts, threads, runtime
+    entries left out)."""
+    if not items:
+        return np.zeros(0, np.int64), np.zeros(0, np.int64), \
+            np.zeros(0, np.float64), 0, 0
+    bids, cnts, excluded = zip(*(it.set_entries() for it in items))
+    lens = np.fromiter(map(len, bids), np.int64, count=len(bids))
+    return (lens, np.concatenate(bids),
+            np.concatenate(cnts, dtype=np.float64),
+            sum(it.num_threads for it in items), sum(excluded))
+
+
 def batch_set_ids(intervals, index: BBEIndex, max_set: int):
-    """Vectorized interval-set assembly WITHOUT the BBE payload: one
-    stable sort selects each interval's top-`max_set` blocks by count
-    (same order and tie-breaking as the per-interval loop), one lookup
-    maps bids to matrix rows. Shared by inference batching (pipeline)
-    and Stage-2 training batches (repro.train.stage2).
+    """Vectorized set assembly WITHOUT the BBE payload, for `Interval`s
+    and multi-threaded `Region`s alike (an interval is the one-thread
+    case): one stable sort selects each item's top-`max_set` entries by
+    count (same order and tie-breaking as the per-interval loop), one
+    lookup maps bids to matrix rows. Shared by inference batching
+    (pipeline) and Stage-2 training batches (repro.train.stage2).
+
+    Gives the innermost open span the batch's `threads`, the `entries`
+    kept, the runtime entries `excluded` and the entries `truncated`
+    past `max_set`.
 
     Returns (row_ids (B,N) int32 — `index.sentinel` in empty slots,
     freqs (B,N) f32, mask (B,N) bool)."""
@@ -129,30 +148,22 @@ def batch_set_ids(intervals, index: BBEIndex, max_set: int):
     row_ids = np.full((B, N), index.sentinel, np.int32)
     freqs = np.zeros((B, N), np.float32)
     mask = np.zeros((B, N), bool)
-    lens = np.fromiter((len(iv.counts) for iv in intervals), np.int64,
-                       count=B)
-    total = int(lens.sum())
-    if total == 0:
-        return row_ids, freqs, mask
-    bids = np.empty(total, np.int64)
-    cnts = np.empty(total, np.float64)
-    off = 0
-    for iv in intervals:
-        c = iv.counts
-        n = len(c)
-        bids[off:off + n] = np.fromiter(c.keys(), np.int64, count=n)
-        cnts[off:off + n] = np.fromiter(c.values(), np.float64, count=n)
-        off += n
-    seg = np.repeat(np.arange(B), lens)
-    order = _topk_order(seg, cnts)
-    starts = np.concatenate(([0], np.cumsum(lens)[:-1]))
-    pos = np.arange(total) - np.repeat(starts, lens)
-    keep = pos < N
-    rows = index.rows(bids[order][keep])
-    b_idx, n_idx = seg[keep], pos[keep]   # seg[order] == seg (grouped)
-    row_ids[b_idx, n_idx] = rows
-    freqs[b_idx, n_idx] = cnts[order][keep]
-    mask[b_idx, n_idx] = True
+    lens, bids, cnts, threads, excluded = _set_entries(intervals)
+    total = kept = bids.size
+    if total:
+        seg = np.repeat(np.arange(B), lens)
+        order = _topk_order(seg, cnts)
+        starts = np.concatenate(([0], np.cumsum(lens)[:-1]))
+        pos = np.arange(total) - np.repeat(starts, lens)
+        keep = pos < N
+        rows = index.rows(bids[order][keep])
+        b_idx, n_idx = seg[keep], pos[keep]   # seg[order] == seg (grouped)
+        row_ids[b_idx, n_idx] = rows
+        freqs[b_idx, n_idx] = cnts[order][keep]
+        mask[b_idx, n_idx] = True
+        kept = rows.size
+    tracing.add(threads=threads, entries=kept, excluded=excluded,
+                truncated=total - kept)
     return row_ids, freqs, mask
 
 

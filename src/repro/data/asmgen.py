@@ -331,6 +331,19 @@ SPEC_FP_LIKE = [
     ("649.fotonik3d", "streaming"),
 ]
 
+# The 8 NAS Parallel Benchmarks (NPB 3, OpenMP) analogues that LoopPoint
+# samples multi-threaded; each profile is the nearest existing one.
+NPB_LIKE = [
+    ("npb.bt", "fp_compute"),       # block-tridiagonal solver, dense fp
+    ("npb.cg", "mixed"),            # sparse matrix-vector, indirect loads
+    ("npb.ep", "fp_compute"),       # random pairs, tiny working set
+    ("npb.ft", "streaming"),        # 3-D FFT, strided transposes
+    ("npb.is", "pointer_chase"),    # integer bucket sort, random scatter
+    ("npb.lu", "fp_compute"),       # SSOR sweeps, dense fp
+    ("npb.mg", "streaming"),        # multigrid stencils, bandwidth-bound
+    ("npb.sp", "fp_compute"),       # scalar pentadiagonal solver, dense fp
+]
+
 
 def spec_programs(which: str = "int") -> List[Program]:
     table = SPEC_INT_LIKE if which == "int" else SPEC_FP_LIKE

@@ -23,13 +23,23 @@ INTERVAL_INSTRS = 10_000_000  # paper: 10M-instruction intervals
 
 @dataclass
 class Interval:
-    """One sampling interval of a program's execution."""
+    """One sampling interval of a program's execution: one thread, the
+    T = 1 case of a `Region`."""
     program: str
     index: int               # position within the program's trace
     counts: Dict[int, int]   # block id -> execution count
     phase_id: int
     working_scale: float     # memory pressure multiplier for this interval
     num_instrs: int
+
+    num_threads = 1
+
+    def set_entries(self):
+        """-> (block ids, counts) of the set's candidate entries in the
+        dict's order, and 0 runtime entries left out (`Region`'s)."""
+        n = len(self.counts)
+        return (np.fromiter(self.counts.keys(), np.int64, count=n),
+                np.fromiter(self.counts.values(), np.float64, count=n), 0)
 
     def bbv(self, block_order: List[int], weight_by_len: bool = True,
             block_lens: Dict[int, int] = None) -> np.ndarray:
@@ -43,6 +53,48 @@ class Interval:
                 v[idx[bid]] = w
         s = v.sum()
         return v / s if s > 0 else v
+
+
+@dataclass
+class Region:
+    """One multi-threaded sampling region, LoopPoint's unit (Sabu et al.,
+    HPCA 2022): T threads over one window of the run.
+
+    Thread t executed block `bids[j]` `counts[t, j]` times. `runtime[j]`
+    marks a block of the threading runtime's image (its synchronisation
+    and spin code), which LoopPoint filters by image: runtime entries are
+    left out of the region's set and carry no instructions into its
+    weight.
+
+    The region's set holds every (t, j) with `counts[t, j] > 0` and block
+    j in the main image, each with the block's BBE and the frequency
+    `counts[t, j]`, cut to the top `max_set` by count; ties keep the
+    entries' order, thread by thread, then `bids` order within a thread
+    (`repro.core.pipeline.batch_set_ids`). So a thread's imbalance or a
+    master thread's serial section shows in the set, while a balanced
+    region's T identical copies give the one-thread interval's signature.
+    `num_instrs`, the region's store weight, is the main-image
+    instructions summed over threads. A single-thread `Interval` is the
+    T = 1 case."""
+    program: str
+    index: int
+    bids: np.ndarray         # (B,) int64 block ids
+    counts: np.ndarray       # (T, B) int64 executions per thread
+    runtime: np.ndarray      # (B,) bool: block of the threading runtime
+    num_instrs: int          # main-image instructions, all threads
+
+    @property
+    def num_threads(self) -> int:
+        return self.counts.shape[0]
+
+    def set_entries(self):
+        """-> (block ids, counts) of the set's candidate entries, thread
+        by thread, and how many runtime entries with a count were left
+        out."""
+        main = (self.counts > 0) & ~self.runtime
+        _, block = np.nonzero(main)
+        return (self.bids[block], self.counts[main],
+                int(np.count_nonzero(self.counts[:, self.runtime])))
 
 
 def trace_program(program: Program, n_intervals: int,

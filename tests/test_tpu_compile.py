@@ -21,7 +21,10 @@ from repro.kernels.set_attention.ops import masked_set_attention
 from repro.kernels.wkv.ops import wkv_chunked
 
 STORE_ROWS, SIG_DIM, K = 131072, 128, 14       # 10^5 rows at capacity
-SET_B, SET_H, SET_N, SET_DH = 512, 4, 64, 64   # SignatureConfig() widths
+SET_B, SET_H, SET_DH = 512, 4, 64             # SignatureConfig() widths
+# max_set: SignatureConfig()'s 64, and 512 (8 threads x 64) of the
+# multi-threaded regions' configuration
+SET_NS = (64, 512)
 WKV_B, WKV_S, WKV_H, WKV_DH = 256, 128, 6, 64  # BBEConfig() widths
 
 
@@ -53,13 +56,17 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("n_queries", [SET_N, 1], ids=["sab", "pma"])
+@pytest.mark.parametrize("block,set_n", [
+    (b, n) for n in SET_NS for b in ("sab", "pma")],
+    ids=[b if n == SET_NS[0] else f"{b}-{n}" for n in SET_NS
+         for b in ("sab", "pma")])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_set_attention_compiles(one_chip, n_queries, direction):
+def test_set_attention_compiles(one_chip, block, set_n, direction):
+    n_queries = set_n if block == "sab" else 1
     q = _spec(one_chip, (SET_B, SET_H, n_queries, SET_DH))
-    kv = _spec(one_chip, (SET_B, SET_H, SET_N, SET_DH))
-    bias = _spec(one_chip, (SET_B, SET_N))
-    mask = _spec(one_chip, (SET_B, SET_N), jnp.bool_)
+    kv = _spec(one_chip, (SET_B, SET_H, set_n, SET_DH))
+    bias = _spec(one_chip, (SET_B, set_n))
+    mask = _spec(one_chip, (SET_B, set_n), jnp.bool_)
 
     def fwd(q, k, v, b, m):
         return masked_set_attention(q, k, v, b, m)
@@ -93,9 +100,10 @@ def test_kmeans_update_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_signature_step_compiles(one_chip):
+@pytest.mark.parametrize("set_n", SET_NS)
+def test_signature_step_compiles(one_chip, set_n):
     """The jitted Stage-2 serving step with impl="pallas" at batch 512."""
-    cfg = SignatureConfig()
+    cfg = SignatureConfig(max_set=set_n)
     params = jax.eval_shape(lambda: signature_init(jax.random.PRNGKey(0),
                                                    cfg)[0])
     params = jax.tree_util.tree_map(

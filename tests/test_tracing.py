@@ -135,7 +135,7 @@ def test_no_program_span_is_named_like_a_benchmark_span():
 def tiny_run():
     """A tiny service built over two programs; a third is ingested and
     estimated in two chunks, evicted and vacuumed, and the spans of those
-    six calls are returned with the service."""
+    six calls are returned with the service and the two chunks."""
     from repro.api import SemanticBBVService, ServiceConfig
     from repro.core.bbe import BBEConfig
     from repro.core.signature import SignatureConfig
@@ -169,7 +169,7 @@ def tiny_run():
     svc.evict(new)
     svc.vacuum()
     spans = [s for s in tracing.recent() if s.span_id > mark.span_id]
-    return svc, spans
+    return svc, spans, [ivs[new][:10], ivs[new][10:]]
 
 
 def _tree(spans):
@@ -185,7 +185,7 @@ def _tree(spans):
 
 
 def test_service_span_tree(tiny_run):
-    _, spans = tiny_run
+    _, spans, _ = tiny_run
     ingest = ("service.ingest_intervals", [("pipeline.set_assembly", []),
                                            ("pipeline.stage2", []),
                                            ("store.add", [])])
@@ -210,13 +210,17 @@ def test_service_counts_by_hand(tiny_run):
     archetypes. The first estimate after build assigns the whole store
     in place (one upload, nothing downloaded but the labels); the second
     assigns only its chunk, padded to 16, from the host."""
-    svc, spans = tiny_run
+    svc, spans, chunks = tiny_run
     c = {}
     for s in spans:
         c.setdefault(s.name, []).append(s.counts)
     batch, n_set, sig, cap, k, tail = 32, 48, 16, 64, 3, 16
-    assert c["pipeline.set_assembly"] == 2 * [{"rows": 10,
-                                               "padded_rows": batch - 10}]
+    # one thread an interval, no runtime image; each set's blocks, to 48
+    blocks = [[len(iv.counts) for iv in chunk] for chunk in chunks]
+    assert c["pipeline.set_assembly"] == [
+        {"rows": 10, "padded_rows": batch - 10, "threads": 10,
+         "entries": sum(min(n, n_set) for n in b), "excluded": 0,
+         "truncated": sum(max(n - n_set, 0) for n in b)} for b in blocks]
     stage2_up = batch * n_set * (4 + 4 + 1)     # row ids, freqs, mask
     stage2_down = batch * (sig + 1) * 4         # signatures, log CPI
     assert c["pipeline.stage2"] == 2 * [{"h2d_bytes": stage2_up,
