@@ -16,6 +16,13 @@ Typical flow:
     svc.ingest_intervals("new", ...)  # later, unseen program
     est = svc.estimate("new")         # attach (no re-clustering) + CPI
 
+`ingest_intervals`, `attach_intervals` and `attach_many` (given
+intervals) run the Stage-2 step, which has one static shape per set
+width (`repro.core.pipeline.set_width`: 32, 64, ... up to `max_set`) and
+per BBE table size. The first of these calls to meet a width not seen
+before, or to follow an `ingest_blocks` that grew the table, compiles
+the step once, inside that call.
+
 Configuration is ONE typed dataclass (`ServiceConfig`) instead of the
 kwargs sprawl that used to be spread over `SemanticBBVPipeline.create`
 and `benchmarks.lab.get_pipeline`.

@@ -10,13 +10,18 @@ Typical flow (see examples/):
     cpi = pipe.predict_interval_cpi(intervals, bbe_table)
 
 Host-side batching is fully vectorized: `encode_blocks` memoizes BBEs in
-an LRU cache keyed by block content, every jitted entry point sees one
-static batch shape (partial chunks are padded, never retraced), and
-interval sets are assembled through `BBEIndex` — the contiguous BBE
-matrix is uploaded to the device once per call and each batch ships only
-(row_ids, freqs, mask); the (B, N, bbe_dim) gather happens on-device
-inside the jitted signature step. At 100k+ intervals the pipeline is
-bound by device compute, not Python.
+an LRU cache keyed by block content, and partial chunks are padded to the
+full batch, never retraced, so Stage 1 compiles one shape. Each Stage-2
+row runs at the width of its own set, rounded up a short ladder of
+powers of two capped at `max_set` (`set_width`); rows are batched by
+width, so a row's signature never depends on the rows it is batched
+with. A width not seen before compiles once, mid-request, and every
+later batch of that width reuses it. Interval sets are assembled
+through `BBEIndex` — the contiguous BBE matrix is uploaded to the device
+once per call and each batch ships only (row_ids, freqs, mask); the
+(B, N, bbe_dim) gather happens on-device inside the jitted signature
+step. At 100k+ intervals the pipeline is bound by device compute, not
+Python.
 """
 from __future__ import annotations
 
@@ -165,6 +170,18 @@ def batch_set_ids(intervals, index: BBEIndex, max_set: int):
     tracing.add(threads=threads, entries=kept, excluded=excluded,
                 truncated=total - kept)
     return row_ids, freqs, mask
+
+
+def set_width(size: int, max_set: int) -> int:
+    """Slots a Stage-2 row with `size` set entries runs at: the smallest
+    power of two that holds them, at least 32, capped at `max_set`, which
+    is the last step even when it is not a power of two. Below 32 slots
+    the step's work is too small to be worth a shape of its own (the
+    set-attention kernel pads keys to 128 lanes anyway), so the ladder
+    stays at 5 widths for `max_set` 512. Slots past a set's last entry
+    are masked and add exactly zero, so any width that holds the set
+    gives the same signature, to the order of summation."""
+    return min(max_set, max(32, 1 << (int(size) - 1).bit_length()))
 
 
 def _signature_from_rows(params, cfg, matrix, row_ids, freqs, mask,
@@ -362,41 +379,67 @@ class SemanticBBVPipeline:
     def _run_signature(self, intervals, bbe_table, batch: int):
         """Shared batched Stage-2 driver -> (sigs (B,sig_dim), logcpi (B,)).
 
-        The BBE matrix goes to the device once; each batch ships only
-        integer row ids + freqs + mask, and the last partial batch is
-        padded to the static `batch` shape (all-masked rows, outputs
-        discarded) so it reuses the same compile."""
+        Each item runs at `set_width` of its own set: items are grouped
+        by width, in order within a group, and each group is cut into
+        batches of `batch`. A row's result therefore depends on its set
+        alone, never on the items it shares a batch with: any split of
+        the same items into calls gives the same bits. The BBE matrix
+        goes to the device once; each batch ships only integer row ids +
+        freqs + mask at its width (`batch_set_ids` packs each set's
+        entries to the left, so the slice drops only masked slots). A
+        group's last partial batch is padded to `batch` rows (all-masked,
+        outputs discarded). Each width is one static shape, and so is
+        the matrix: a width not seen before, or any width after the BBE
+        table has grown, compiles once, mid-request."""
         fn = self._jit(f"signature_{self.impl}", lambda: jax.jit(
             functools.partial(_signature_from_rows, cfg=self.sig_cfg,
                               impl=self.impl)))
         index, matrix = self._table_index(bbe_table)
-        sigs, cpis = [], []
-        for i in range(0, len(intervals), batch):
-            with tracing.span("pipeline.set_assembly") as s:
-                row_ids, freqs, mask = self._batch_set_ids(
-                    intervals[i:i + batch], index)
-                got = row_ids.shape[0]
-                if got < batch:
-                    pad = batch - got
-                    row_ids = np.pad(row_ids, ((0, pad), (0, 0)),
-                                     constant_values=index.sentinel)
-                    freqs = np.pad(freqs, ((0, pad), (0, 0)))
-                    mask = np.pad(mask, ((0, pad), (0, 0)))
-                s.add(rows=got, padded_rows=batch - got)
-            with tracing.span("pipeline.stage2") as s:
-                sig, logcpi = fn(params=self.sig_params, matrix=matrix,
-                                 row_ids=jnp.asarray(row_ids),
-                                 freqs=jnp.asarray(freqs),
-                                 mask=jnp.asarray(mask))
-                sig, logcpi = np.asarray(sig), np.asarray(logcpi)
-                s.add(h2d_bytes=row_ids.nbytes + freqs.nbytes + mask.nbytes,
-                      d2h_bytes=sig.nbytes + logcpi.nbytes)
-            sigs.append(sig[:got])
-            cpis.append(logcpi[:got])
+        widths = np.fromiter(
+            (set_width(it.set_size(), self.sig_cfg.max_set)
+             for it in intervals), np.int64, count=len(intervals))
+        done, sigs, cpis = [], [], []
+        for width in np.unique(widths).tolist():
+            group = np.flatnonzero(widths == width)
+            for i in range(0, len(group), batch):
+                take = group[i:i + batch]
+                sig, logcpi = self._run_batch(
+                    fn, [intervals[j] for j in take], index, matrix,
+                    batch, width)
+                done.append(take)
+                sigs.append(sig)
+                cpis.append(logcpi)
         if not sigs:
             return (np.zeros((0, self.sig_cfg.sig_dim), np.float32),
                     np.zeros((0,), np.float32))
-        return np.concatenate(sigs, axis=0), np.concatenate(cpis, axis=0)
+        order = np.argsort(np.concatenate(done))
+        return (np.concatenate(sigs, axis=0)[order],
+                np.concatenate(cpis, axis=0)[order])
+
+    def _run_batch(self, fn, items, index, matrix, batch: int, width: int):
+        """One Stage-2 call: `items` (at most `batch`, each set at most
+        `width` entries) padded to `batch` rows of `width` slots."""
+        with tracing.span("pipeline.set_assembly") as s:
+            row_ids, freqs, mask = self._batch_set_ids(items, index)
+            row_ids, freqs, mask = (row_ids[:, :width], freqs[:, :width],
+                                    mask[:, :width])
+            got = row_ids.shape[0]
+            if got < batch:
+                pad = batch - got
+                row_ids = np.pad(row_ids, ((0, pad), (0, 0)),
+                                 constant_values=index.sentinel)
+                freqs = np.pad(freqs, ((0, pad), (0, 0)))
+                mask = np.pad(mask, ((0, pad), (0, 0)))
+            s.add(rows=got, padded_rows=batch - got, width=width)
+        with tracing.span("pipeline.stage2") as s:
+            sig, logcpi = fn(params=self.sig_params, matrix=matrix,
+                             row_ids=jnp.asarray(row_ids),
+                             freqs=jnp.asarray(freqs),
+                             mask=jnp.asarray(mask))
+            sig, logcpi = np.asarray(sig), np.asarray(logcpi)
+            s.add(h2d_bytes=row_ids.nbytes + freqs.nbytes + mask.nbytes,
+                  d2h_bytes=sig.nbytes + logcpi.nbytes)
+        return sig[:got], logcpi[:got]
 
     def interval_signatures(self, intervals, bbe_table, batch: int = 512
                             ) -> np.ndarray:
@@ -413,11 +456,12 @@ class SemanticBBVPipeline:
         """Signatures for SEVERAL programs in one pipelined batch stream.
 
         Intervals are concatenated across programs before batching, so
-        the static-shape padding penalty of a partial batch is paid once
-        at the end of the stream — not once per program — and the BBE
-        matrix upload plus jit cache are shared across the whole call.
-        Returns {program: (n_p, sig_dim)} in input order; bit-identical
-        to per-program `interval_signatures` calls.
+        the padding penalty of a partial batch is paid once per set width
+        over the whole stream — not once per program — and the BBE matrix
+        upload plus jit cache are shared across the whole call. Returns
+        {program: (n_p, sig_dim)} in input order; bit-identical to
+        per-program `interval_signatures` calls (a row runs at the width
+        of its own set, whatever it is batched with).
         """
         names = list(intervals_by_program)
         flat = [iv for n in names for iv in intervals_by_program[n]]

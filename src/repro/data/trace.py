@@ -41,6 +41,10 @@ class Interval:
         return (np.fromiter(self.counts.keys(), np.int64, count=n),
                 np.fromiter(self.counts.values(), np.float64, count=n), 0)
 
+    def set_size(self) -> int:
+        """How many entries `set_entries()` gives, without building them."""
+        return len(self.counts)
+
     def bbv(self, block_order: List[int], weight_by_len: bool = True,
             block_lens: Dict[int, int] = None) -> np.ndarray:
         """Classic BBV: per-block execution counts (optionally × block size),
@@ -95,6 +99,10 @@ class Region:
         _, block = np.nonzero(main)
         return (self.bids[block], self.counts[main],
                 int(np.count_nonzero(self.counts[:, self.runtime])))
+
+    def set_size(self) -> int:
+        """How many entries `set_entries()` gives, without building them."""
+        return int(np.count_nonzero((self.counts > 0) & ~self.runtime))
 
 
 def trace_program(program: Program, n_intervals: int,

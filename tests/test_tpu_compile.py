@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.pipeline import _signature_from_rows
+from repro.core.pipeline import _signature_from_rows, set_width
 from repro.core.signature import SignatureConfig, signature_init
 from repro.kernels.kmeans_assign.ops import kmeans_assign, kmeans_update
 from repro.kernels.set_attention.ops import masked_set_attention
@@ -23,8 +23,10 @@ from repro.kernels.wkv.ops import wkv_chunked
 STORE_ROWS, SIG_DIM, K = 131072, 128, 14       # 10^5 rows at capacity
 SET_B, SET_H, SET_DH = 512, 4, 64             # SignatureConfig() widths
 # max_set: SignatureConfig()'s 64, and 512 (8 threads x 64) of the
-# multi-threaded regions' configuration
-SET_NS = (64, 512)
+# multi-threaded regions' configuration; then every other set width a
+# served batch can run at (`set_width`'s ladder up to 512)
+SET_NS = (64, 512) + tuple(sorted(
+    {set_width(n, 512) for n in range(513)} - {64, 512}))
 WKV_B, WKV_S, WKV_H, WKV_DH = 256, 128, 6, 64  # BBEConfig() widths
 
 

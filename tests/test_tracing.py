@@ -206,7 +206,8 @@ def test_service_span_tree(tiny_run):
 
 def test_service_counts_by_hand(tiny_run):
     """Store: 2 x 16 rows + two chunks of 10 new rows of sig_dim 16 at
-    capacity 64; Stage 2: one batch of 32 sets of 48 per chunk; k = 3
+    capacity 64; Stage 2: per chunk, one batch of 32 sets for each width
+    of the ladder 32, 48 (max_set) that holds some of its sets; k = 3
     archetypes. The first estimate after build assigns the whole store
     in place (one upload, nothing downloaded but the labels); the second
     assigns only its chunk, padded to 16, from the host."""
@@ -217,14 +218,23 @@ def test_service_counts_by_hand(tiny_run):
     batch, n_set, sig, cap, k, tail = 32, 48, 16, 64, 3, 16
     # one thread an interval, no runtime image; each set's blocks, to 48
     blocks = [[len(iv.counts) for iv in chunk] for chunk in chunks]
+
+    def width(n):
+        return 32 if n <= 32 else n_set
+    batches = [(w, [n for n in b if width(n) == w])
+               for b in blocks for w in sorted({width(n) for n in b})]
+    assert [w for w, _ in batches] == [32, 32]   # sets of 31 blocks
     assert c["pipeline.set_assembly"] == [
-        {"rows": 10, "padded_rows": batch - 10, "threads": 10,
-         "entries": sum(min(n, n_set) for n in b), "excluded": 0,
-         "truncated": sum(max(n - n_set, 0) for n in b)} for b in blocks]
-    stage2_up = batch * n_set * (4 + 4 + 1)     # row ids, freqs, mask
+        {"rows": len(g), "padded_rows": batch - len(g), "threads": len(g),
+         "entries": sum(min(n, n_set) for n in g), "excluded": 0,
+         "truncated": sum(max(n - n_set, 0) for n in g), "width": w}
+        for w, g in batches]
+    # row ids, freqs, mask at each batch's width
+    stage2_up = [batch * w * (4 + 4 + 1) for w, _ in batches]
     stage2_down = batch * (sig + 1) * 4         # signatures, log CPI
-    assert c["pipeline.stage2"] == 2 * [{"h2d_bytes": stage2_up,
-                                         "d2h_bytes": stage2_down}]
+    assert c["pipeline.stage2"] == [{"h2d_bytes": up,
+                                     "d2h_bytes": stage2_down}
+                                    for up in stage2_up]
     assert c["store.add"] == 2 * [{"rows": 10}]
     store = cap * sig * 4
     assert c["store.upload"] == [{"h2d_bytes": store}]
@@ -247,6 +257,7 @@ def test_service_counts_by_hand(tiny_run):
     counts = [x for v in c.values() for x in v]
     h2d = sum(x.get("h2d_bytes", 0) for x in counts)
     d2h = sum(x.get("d2h_bytes", 0) for x in counts)
-    assert h2d == (2 * stage2_up + store + 2 * k * sig * 4
+    assert h2d == (sum(stage2_up) + store + 2 * k * sig * 4
                    + tail * sig * 4)
-    assert d2h == 2 * stage2_down + 2 * cap * 4 + 2 * tail * 4
+    assert d2h == (len(batches) * stage2_down + 2 * cap * 4
+                   + 2 * tail * 4)
