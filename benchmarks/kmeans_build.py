@@ -10,7 +10,7 @@ build paths are timed on identical synthetic signature blobs:
            path existed).
   device   `kmeans_device`: ALL restarts inside one jitted call over the
            padded device-resident matrix (`n_valid` masks the pad tail —
-           exactly how `KnowledgeBase.build(impl="device")` consumes
+           exactly how `KnowledgeBase(build_impl="device").build` consumes
            `SignatureStore.device_matrix`), expansion-form ++ init,
            best-of argmin on device.
 
@@ -88,7 +88,7 @@ def run():
         lambda: kmeans_device(x, K, iters=ITERS, restarts=RESTARTS,
                               seed=0, use_kernel=use_kernel))
     # the store path: padded capacity matrix + n_valid mask (what
-    # KnowledgeBase.build(impl="device") actually runs)
+    # KnowledgeBase(build_impl="device").build actually runs)
     t_dev_pad = _time_us(
         lambda: kmeans_device(xp, K, iters=ITERS, restarts=RESTARTS,
                               seed=0, use_kernel=use_kernel,

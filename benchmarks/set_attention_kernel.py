@@ -41,11 +41,6 @@ def _time_us(fn, repeat: int = 5) -> float:
     return 1e6 * sorted(ts)[len(ts) // 2]
 
 
-def _pallas_interpret() -> bool:
-    """Interpreter off only where the kernel can actually lower (TPU)."""
-    return jax.default_backend() != "tpu"
-
-
 def _inputs(rng, B, H, N, dh):
     q = jnp.asarray(rng.randn(B, H, N, dh), jnp.float32)
     k = jnp.asarray(rng.randn(B, H, N, dh), jnp.float32)
@@ -59,13 +54,10 @@ def _bench_kernel(B=64, H=4, N=64, dh=64):
     from repro.kernels.set_attention.ops import masked_set_attention
     from repro.kernels.set_attention.ref import set_attention_reference
     q, k, v, bias, mask = _inputs(np.random.RandomState(0), B, H, N, dh)
-    interp = _pallas_interpret()
     xla = jax.jit(set_attention_reference)
     t_xla = _time_us(lambda: xla(q, k, v, bias, mask))
-    t_pal = _time_us(
-        lambda: masked_set_attention(q, k, v, bias, mask,
-                                     interpret=interp),
-        repeat=3)
+    t_pal = _time_us(lambda: masked_set_attention(q, k, v, bias, mask),
+                     repeat=3)
     return t_xla, t_pal
 
 
@@ -78,7 +70,6 @@ def _bench_kernel_grad(B=16, H=4, N=64, dh=64):
     from repro.kernels.set_attention.ops import masked_set_attention
     from repro.kernels.set_attention.ref import set_attention_reference
     q, k, v, bias, mask = _inputs(np.random.RandomState(1), B, H, N, dh)
-    interp = _pallas_interpret()
 
     def scalar(fn):
         return lambda q, k, v, b: jnp.sum(
@@ -86,9 +77,8 @@ def _bench_kernel_grad(B=16, H=4, N=64, dh=64):
 
     g_xla = jax.jit(jax.value_and_grad(scalar(set_attention_reference),
                                        argnums=(0, 1, 2, 3)))
-    g_pal = jax.jit(jax.value_and_grad(
-        scalar(lambda *a: masked_set_attention(*a, interpret=interp)),
-        argnums=(0, 1, 2, 3)))
+    g_pal = jax.jit(jax.value_and_grad(scalar(masked_set_attention),
+                                       argnums=(0, 1, 2, 3)))
     t_xla = _time_us(lambda: g_xla(q, k, v, bias))
     t_pal = _time_us(lambda: g_pal(q, k, v, bias), repeat=3)
     return t_xla, t_pal
@@ -125,7 +115,8 @@ def _bench_batch_sets(n_intervals=512, set_size=64, n_blocks=4096):
 
 def run():
     backend = jax.default_backend()
-    mode = "interpret" if _pallas_interpret() else "compiled"
+    # the kernel lowers compiled on a TPU, interpreted anywhere else
+    mode = "compiled" if backend == "tpu" else "interpret"
     t_xla, t_pal = _bench_kernel()
     tg_xla, tg_pal = _bench_kernel_grad()
     t_loop, t_ids, t_dense = _bench_batch_sets()

@@ -4,8 +4,7 @@
 #   lifecycle.py  EvictionPolicy / vacuum: TTL+LRU eviction, compaction
 #   service.py    SemanticBBVService facade + typed ServiceConfig
 from repro.api.knowledge import (
-    ASSIGN_IMPLS, BUILD_IMPLS, CPIEstimate, KnowledgeBase,
-    assign_signatures, resolve_assign_impl, resolve_build_impl,
+    CPIEstimate, KnowledgeBase, assign_signatures,
 )
 from repro.api.lifecycle import (
     EvictionPolicy, VacuumReport, select_victims, vacuum,
@@ -14,9 +13,7 @@ from repro.api.service import SemanticBBVService, ServiceConfig
 from repro.api.store import SignatureStore
 
 __all__ = [
-    "ASSIGN_IMPLS", "BUILD_IMPLS", "CPIEstimate", "EvictionPolicy",
-    "KnowledgeBase", "SemanticBBVService", "ServiceConfig",
-    "SignatureStore", "VacuumReport", "assign_signatures",
-    "resolve_assign_impl", "resolve_build_impl", "select_victims",
-    "vacuum",
+    "CPIEstimate", "EvictionPolicy", "KnowledgeBase", "SemanticBBVService",
+    "ServiceConfig", "SignatureStore", "VacuumReport", "assign_signatures",
+    "select_victims", "vacuum",
 ]

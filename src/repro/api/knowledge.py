@@ -14,12 +14,11 @@ service instead of a one-shot function:
               rep-CPI dot product, clamped accuracy when ground truth is
               known, and the weight-aware speedup.
 
-Assignment backend is selectable per base (`assign_impl`):
-  "reference"         jnp nearest-centroid (kmeans_assign_reference)
-  "numpy"             pure-numpy oracle (parity tests)
-  "pallas"            compiled `kmeans_assign` Pallas kernel (TPU)
-  "pallas_interpret"  same kernel under the interpreter (CPU parity)
-  "auto"              "pallas" on TPU, "reference" elsewhere
+Each base has two backend options, each the jnp reference or the Pallas
+kernel; where the kernel is picked it runs compiled on a TPU and
+interpreted anywhere else (repro/kernels/__init__.py):
+  assign_impl  "reference" (default) | "pallas"         nearest archetype
+  build_impl   "device" (default)    | "device_kernel"  k-means restarts
 
 Stored rows are labelled once: the base keeps every row's nearest
 archetype and follows the store through appends, evictions and
@@ -36,79 +35,53 @@ import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.api.store import SignatureStore, _capacity_for
-from repro.core.clustering import kmeans, kmeans_device, representatives
+from repro.core.clustering import kmeans_device, representatives
 from repro.core.crossprog import cpi_accuracy, speedup
+from repro.kernels import check_backend
 from repro.train.checkpoint import (
     latest_checkpoint, restore_checkpoint, save_checkpoint,
 )
 from repro.utils import tracing
 
-ASSIGN_IMPLS = ("auto", "reference", "numpy", "pallas", "pallas_interpret")
-
-# build() backend: where the universal-clustering restart loop runs.
-#   "host"           legacy numpy round-trip per restart (parity anchor)
-#   "device"         one jitted restart loop over the store's padded
-#                    device matrix (jnp assignment/segment-reduce)
-#   "device_kernel"  same loop with the Pallas kmeans kernels inside
-#                    (compiled on TPU, interpreter elsewhere)
-#   "auto"           "device_kernel" on TPU, "device" elsewhere
-BUILD_IMPLS = ("auto", "host", "device", "device_kernel")
-
-
-def resolve_assign_impl(impl: str) -> str:
-    if impl not in ASSIGN_IMPLS:
-        raise ValueError(f"assign_impl must be one of {ASSIGN_IMPLS}, "
-                         f"got {impl!r}")
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "reference"
-    return impl
-
-
-def resolve_build_impl(impl: str) -> str:
-    if impl not in BUILD_IMPLS:
-        raise ValueError(f"build_impl must be one of {BUILD_IMPLS}, "
-                         f"got {impl!r}")
-    if impl == "auto":
-        return ("device_kernel" if jax.default_backend() == "tpu"
-                else "device")
-    return impl
+_ASSIGN_BACKENDS = ("reference", "pallas")
+_BUILD_BACKENDS = ("device", "device_kernel")
+# values an older `save` may have written, read by `load` as their successors
+_RETIRED = {
+    "assign_impl": {"numpy": "reference", "pallas_interpret": "pallas",
+                    "auto": "pallas"},
+    "build_impl": {"host": "device"},
+}
 
 
 def assign_signatures(signatures: np.ndarray, centroids: np.ndarray,
                       impl: str = "reference"
                       ) -> Tuple[np.ndarray, np.ndarray]:
-    """Batched nearest-centroid: (assign (N,) int32, dist2 (N,) f32).
-
-    The impl switch mirrors the set-attention kernels: a numpy oracle,
-    the jnp reference, and the Pallas `kmeans_assign` kernel (compiled
-    or interpreted) — all parity-tested against each other.
+    """Batched nearest-centroid: (assign (N,) int32, dist2 (N,) f32),
+    by the jnp reference or the Pallas `kmeans_assign` kernel
+    (parity-tested against each other).
 
     `signatures` may be a float32 device array (the store's resident
-    `device_matrix`): the jnp and Pallas backends read it where it is,
-    with no host copy and no upload.
+    `device_matrix`): both backends read it where it is, with no host
+    copy and no upload.
     """
-    impl = resolve_assign_impl(impl)
+    if impl == "numpy":
+        # chipbench/tests still name the retired numpy oracle; this goes
+        # with the backend fields themselves (ROADMAP Queue 3 item 1)
+        impl = "reference"
+    impl = check_backend("assign_impl", impl, _ASSIGN_BACKENDS)
     on_device = isinstance(signatures, jax.Array)
     x = signatures if on_device else np.asarray(signatures, np.float32)
     c = np.asarray(centroids, np.float32)
-    if impl == "numpy":
-        if on_device:
-            x = np.asarray(x, np.float32)
-            tracing.add(d2h_bytes=x.nbytes)
-        d2 = (np.sum(x * x, -1, keepdims=True) - 2.0 * (x @ c.T)
-              + np.sum(c * c, -1)[None, :])
-        return d2.argmin(-1).astype(np.int32), d2.min(-1).astype(np.float32)
-    import jax.numpy as jnp
     if impl == "reference":
         from repro.kernels.kmeans_assign.ref import kmeans_assign_reference
         a, d2 = kmeans_assign_reference(jnp.asarray(x), jnp.asarray(c))
     else:
         from repro.kernels.kmeans_assign.ops import kmeans_assign
-        a, d2 = kmeans_assign(jnp.asarray(x), jnp.asarray(c),
-                              interpret=(impl == "pallas_interpret"))
+        a, d2 = kmeans_assign(jnp.asarray(x), jnp.asarray(c))
     a, d2 = np.asarray(a), np.asarray(d2)
     tracing.add(h2d_bytes=(0 if on_device else x.nbytes) + c.nbytes,
                 d2h_bytes=a.nbytes + d2.nbytes)
@@ -144,10 +117,12 @@ class KnowledgeBase:
 
     def __init__(self, store: SignatureStore, *,
                  assign_impl: str = "reference",
-                 build_impl: str = "host"):
+                 build_impl: str = "device"):
         self.store = store
-        self.assign_impl = assign_impl
-        self.build_impl = build_impl
+        self.assign_impl = check_backend("assign_impl", assign_impl,
+                                         _ASSIGN_BACKENDS)
+        self.build_impl = check_backend("build_impl", build_impl,
+                                        _BUILD_BACKENDS)
         self.k = 0
         self.seed = 0
         self.archetypes: Optional[np.ndarray] = None   # (k, d)
@@ -178,7 +153,7 @@ class KnowledgeBase:
 
     # -------------------------------------------------------------- build
     def build(self, k: int = 14, seed: int = 0, *,
-              impl: Optional[str] = None, mesh=None) -> "KnowledgeBase":
+              mesh=None) -> "KnowledgeBase":
         """Universal clustering over every row currently in the store.
 
         Uses the same restart keys and ++ init as the legacy
@@ -187,52 +162,31 @@ class KnowledgeBase:
         one-shot path. Programs ingested AFTER build are attached
         against the frozen archetypes (`attach`), never re-clustered.
 
-        `impl` (default: the base's `build_impl`) picks where the
-        restart loop runs (see BUILD_IMPLS): "host" is the legacy
-        per-restart numpy round-trip; "device"/"device_kernel" run ALL
-        restarts in one jitted call directly over the store's padded
-        `device_matrix` (cluster-aligned compatible with "host"),
-        optionally sharded over `mesh`'s data axes.
+        ALL restarts run in one jitted call directly over the store's
+        padded `device_matrix`, optionally sharded over `mesh`'s data
+        axes, with the jnp ops or the Pallas kernels inside (`build_impl`).
         """
         with tracing.span("kb.build"):
-            return self._build(k, seed, impl, mesh)
+            return self._build(k, seed, mesh)
 
-    def _build(self, k, seed, impl, mesh) -> "KnowledgeBase":
+    def _build(self, k, seed, mesh) -> "KnowledgeBase":
         if self.store.n_alive == 0:
             raise RuntimeError("cannot build a KnowledgeBase over an "
                                "empty SignatureStore (no live rows)")
-        impl = resolve_build_impl(impl or self.build_impl)
-        self.build_impl = impl   # persist the impl actually used (save())
         x = np.asarray(self.store.signatures, np.float32)
-        if not self.store.has_tombstones:
-            if impl == "host":
-                cents, assign, _ = kmeans(x, k, seed=seed)
-            else:
-                cents, assign, _ = kmeans_device(
-                    self.store.device_matrix, k, seed=seed,
-                    use_kernel=(impl == "device_kernel"),
-                    n_valid=len(self.store), mesh=mesh)
-            reps = representatives(x, cents, assign)
-        else:
-            # tombstoned store: dead rows get zero mass. The device path
-            # folds the alive bitmap into the jitted loop's validity
-            # mask (no host filtering); the host path clusters the live
-            # subset and scatters labels back to slot positions.
+        # tombstoned store: dead rows get zero mass, through the alive
+        # bitmap folded into the jitted loop's validity mask
+        tombstoned = self.store.has_tombstones
+        cents, assign, _ = kmeans_device(
+            self.store.device_matrix, k, seed=seed,
+            use_kernel=(self.build_impl == "device_kernel"),
+            n_valid=len(self.store), mesh=mesh,
+            valid_mask=self.store.device_valid if tombstoned else None)
+        if tombstoned:
             alive = self.store.alive_rows
-            if impl == "host":
-                xa = x[alive]
-                cents, a_alive, _ = kmeans(xa, k, seed=seed)
-                assign = np.full(x.shape[0], -1, a_alive.dtype)
-                assign[alive] = a_alive
-                reps = alive[representatives(xa, cents, a_alive)]
-            else:
-                cents, assign, _ = kmeans_device(
-                    self.store.device_matrix, k, seed=seed,
-                    use_kernel=(impl == "device_kernel"),
-                    n_valid=len(self.store), mesh=mesh,
-                    valid_mask=self.store.device_valid)
-                reps = alive[representatives(x[alive], cents,
-                                             assign[alive])]
+            reps = alive[representatives(x[alive], cents, assign[alive])]
+        else:
+            reps = representatives(x, cents, assign)
         self.k = int(cents.shape[0])
         self.seed = seed
         self.archetypes = cents.astype(np.float32)
@@ -534,8 +488,10 @@ class KnowledgeBase:
             for k in keys
         }
         tree, _, meta = restore_checkpoint(path, template)
-        kb = cls(store, assign_impl=meta["assign_impl"],
-                 build_impl=meta.get("build_impl", "host"))
+        impls = {o: _RETIRED[o].get(v, v) for o, v in (
+            ("assign_impl", meta["assign_impl"]),
+            ("build_impl", meta.get("build_impl", "device")))}
+        kb = cls(store, **impls)
         kb.k = int(meta["k"])
         kb.seed = int(meta["seed"])
         kb.archetypes = np.asarray(tree["archetypes"], np.float32)

@@ -51,16 +51,17 @@ class ServiceConfig:
     """Everything a SemanticBBV service instance needs, typed.
 
     `bbe`/`sig` default to the module defaults when None (exactly what
-    `SemanticBBVPipeline.create()` did). `impl` picks the set-attention
-    backend, `assign_impl` the nearest-centroid backend — both are the
-    same switches the kernels expose ("auto" resolves per jax backend).
+    `SemanticBBVPipeline.create()` did). Each backend option picks the
+    jnp reference (the default) or the Pallas kernel; where a kernel is
+    picked, the platform decides whether it runs compiled (TPU) or
+    interpreted (anywhere else), see repro/kernels/__init__.py.
     """
     seed: int = 0
     bbe: Optional[BBEConfig] = None
     sig: Optional[SignatureConfig] = None
-    impl: str = "xla"                 # set-attention: xla|pallas|pallas_interpret
-    assign_impl: str = "reference"    # nearest-centroid: see knowledge.ASSIGN_IMPLS
-    build_impl: str = "host"          # kmeans restart loop: see knowledge.BUILD_IMPLS
+    impl: str = "xla"                 # set attention: xla | pallas
+    assign_impl: str = "reference"    # nearest centroid: reference | pallas
+    build_impl: str = "device"        # k-means build: device | device_kernel
     k: int = 14                       # universal archetypes (paper: 14)
     kmeans_seed: int = 0
     encode_batch: int = 256           # Stage-1 block batch

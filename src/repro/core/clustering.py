@@ -82,9 +82,9 @@ def _assign(x, centroids, use_kernel: bool = False,
     from repro.kernels.kmeans_assign.ops import kmeans_assign
     dax = _row_shard_axes(mesh, x.shape[0])
     if dax is None:
-        return kmeans_assign(x, centroids, interpret=None)
+        return kmeans_assign(x, centroids)
     fn = jax.shard_map(
-        lambda xs, c: kmeans_assign(xs, c, interpret=None),
+        kmeans_assign,
         mesh=mesh, in_specs=(P(dax, None), P(None, None)),
         out_specs=(P(dax), P(dax)), check_vma=False)
     return fn(x, centroids)
@@ -102,10 +102,10 @@ def _update(x, centroids, valid, use_kernel: bool = False,
     from repro.kernels.kmeans_assign.ops import kmeans_update
     dax = _row_shard_axes(mesh, x.shape[0])
     if dax is None:
-        return kmeans_update(x, centroids, valid, interpret=None)
+        return kmeans_update(x, centroids, valid)
 
     def body(xs, c, vs):
-        s, n, i = kmeans_update(xs, c, vs, interpret=None)
+        s, n, i = kmeans_update(xs, c, vs)
         return (jax.lax.psum(s, dax), jax.lax.psum(n, dax),
                 jax.lax.psum(i, dax))
 

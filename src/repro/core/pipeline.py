@@ -38,6 +38,8 @@ from repro.core import bbe as bbe_mod
 from repro.core import signature as sig_mod
 from repro.core.tokenizer import MultiDimTokenizer, default_tokenizer
 from repro.data.isa import BasicBlock
+from repro.kernels import check_backend
+from repro.models.set_transformer import ATTENTION_BACKENDS
 from repro.utils import tracing
 
 _BBE_CACHE_SIZE = 1 << 16
@@ -202,7 +204,7 @@ class PipelineConfig:
     seed: int = 0
     bbe: Optional[bbe_mod.BBEConfig] = None
     sig: Optional[sig_mod.SignatureConfig] = None
-    impl: str = "xla"   # set-attention backend (see repro/kernels)
+    impl: str = "xla"   # set-attention backend: xla | pallas (repro/kernels)
 
     def resolve(self) -> Tuple[bbe_mod.BBEConfig, sig_mod.SignatureConfig]:
         bbe_cfg = self.bbe or bbe_mod.BBEConfig()
@@ -222,7 +224,10 @@ class SemanticBBVPipeline:
     sig_cfg: sig_mod.SignatureConfig
     bbe_params: dict
     sig_params: dict
-    impl: str = "xla"   # Stage-2 attention backend (see repro/kernels)
+    impl: str = "xla"   # Stage-2 attention backend: xla | pallas
+
+    def __post_init__(self):
+        check_backend("impl", self.impl, ATTENTION_BACKENDS)
 
     # ------------------------------------------------------------- factory
     @classmethod

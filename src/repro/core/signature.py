@@ -75,9 +75,9 @@ def signature_apply(params, cfg: SignatureConfig, bbes, freqs, mask,
                     impl: str = "xla"):
     """bbes: (B, N, bbe_dim); freqs: (B, N) execution counts; mask: (B, N).
 
-    impl: attention backend, "xla" | "pallas" | "pallas_interpret"
-    (see repro/kernels/__init__.py); every backend differentiates — the
-    fused kernel has a custom VJP, so training can run impl="pallas".
+    impl: attention backend, "xla" | "pallas" (see
+    repro/kernels/__init__.py); both differentiate — the fused kernel
+    has a custom VJP, so training can run impl="pallas".
 
     Returns (signature (B, sig_dim) L2-normalized, cpi_pred (B,) log1p-CPI)."""
     sig = set_transformer_apply(params["set_transformer"], bbes,
@@ -94,9 +94,9 @@ def stage2_loss(params, cfg: SignatureConfig, batch, impl: str = "xla"):
     """batch: anchor/positive/negative interval sets + anchor CPI.
 
     Each interval set: {bbes (B,N,D), freqs (B,N), mask (B,N)}; 'cpi' (B,).
-    Differentiable under every impl: "pallas"/"pallas_interpret" run the
-    fused set-attention kernel's custom VJP (parity-tested to 1e-4
-    against the "xla" gradients)."""
+    Differentiable under both impls: "pallas" runs the fused
+    set-attention kernel's custom VJP (parity-tested to 1e-4 against the
+    "xla" gradients)."""
     a_sig, a_cpi = signature_apply(params, cfg, batch["anchor"]["bbes"],
                                    batch["anchor"]["freqs"],
                                    batch["anchor"]["mask"], impl)

@@ -1,47 +1,35 @@
-"""jit'd wrappers with padding to the block size."""
+"""Public ops with padding to the block size."""
 from __future__ import annotations
 
-from typing import Optional
-
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import compiled_on_tpu
 from repro.kernels.kmeans_assign.kmeans import (
     kmeans_assign_pallas, kmeans_update_pallas,
 )
 
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    """None -> compiled where the kernel can lower (TPU), interpreter
-    elsewhere — the same auto rule the benchmarks use."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+_assign = compiled_on_tpu(kmeans_assign_pallas)
+_update = compiled_on_tpu(kmeans_update_pallas)
 
 
-def kmeans_assign(x, centroids, block_n: int = 1024,
-                  interpret: Optional[bool] = False):
+def kmeans_assign(x, centroids, block_n: int = 1024):
     """x: (N,d); centroids: (K,d) -> (assign (N,) int32, dist2 (N,) f32)."""
-    interpret = _resolve_interpret(interpret)
     N = x.shape[0]
     bn = min(block_n, max(8, N))
     pad = (-N) % bn
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
-    a, d2 = kmeans_assign_pallas(x, centroids, block_n=bn,
-                                 interpret=interpret)
+    a, d2 = _assign(x, centroids, block_n=bn)
     return a[:N], d2[:N]
 
 
-def kmeans_update(x, centroids, valid=None, block_n: int = 1024,
-                  interpret: Optional[bool] = False):
+def kmeans_update(x, centroids, valid=None, block_n: int = 1024):
     """One fused k-means step: assignment + per-cluster segment reduce.
 
     x: (N,d); centroids: (K,d); valid: optional (N,) mask (None = all
     rows valid; padding added here is always masked out). Returns
     (sums (K,d), counts (K,), inertia scalar), all f32.
     """
-    interpret = _resolve_interpret(interpret)
     N = x.shape[0]
     if valid is None:
         valid = jnp.ones((N,), jnp.float32)
@@ -50,7 +38,6 @@ def kmeans_update(x, centroids, valid=None, block_n: int = 1024,
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
         valid = jnp.pad(valid.astype(jnp.float32), ((0, pad),))
-    sums, counts, inertia = kmeans_update_pallas(
-        x, centroids, valid.astype(jnp.float32)[:, None], block_n=bn,
-        interpret=interpret)
+    sums, counts, inertia = _update(
+        x, centroids, valid.astype(jnp.float32)[:, None], block_n=bn)
     return sums, counts[0], inertia[0, 0]

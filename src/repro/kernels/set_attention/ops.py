@@ -10,15 +10,17 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import compiled_on_tpu
 from repro.kernels.set_attention.set_attn import NEG_INF, set_attention_pallas
+
+_set_attention = compiled_on_tpu(set_attention_pallas)
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def masked_set_attention(q, k, v, key_bias=None, key_mask=None, *,
-                         interpret: bool = False):
+def masked_set_attention(q, k, v, key_bias=None, key_mask=None):
     """Fused masked, frequency-weighted set attention.
 
     q: (B,H,N,dh); k,v: (B,H,M,dh); key_bias: (B,M) additive logit bias;
@@ -44,5 +46,5 @@ def masked_set_attention(q, k, v, key_bias=None, key_mask=None, *,
         bias = bias + jnp.where(key_mask, 0.0, NEG_INF)
     pad_bias = jnp.full((B, Mp - M), 2.0 * NEG_INF, jnp.float32)
     bias = jnp.concatenate([bias, pad_bias], axis=1)
-    o = set_attention_pallas(q, k, v, bias, interpret=interpret)
+    o = _set_attention(q, k, v, bias)
     return o[:, :, :N] if Np != N else o

@@ -3,14 +3,16 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import compiled_on_tpu
 from repro.kernels.wkv.wkv import wkv_pallas
+
+_wkv = compiled_on_tpu(wkv_pallas)
 
 
 def wkv_chunked(r, k, v, w, beta, state: Optional[jnp.ndarray] = None,
-                chunk: int = 128, interpret: bool = False):
+                chunk: int = 128):
     """Delta-rule recurrence via the Pallas kernel.
 
     r,k,v,w: (B,S,H,dh); beta: (B,S,H); state: (B,H,dh,dh) or None.
@@ -26,6 +28,6 @@ def wkv_chunked(r, k, v, w, beta, state: Optional[jnp.ndarray] = None,
     c = min(chunk, S) if S % min(chunk, S) == 0 else S
     if S % c:
         c = S  # fallback: single chunk
-    y, sf = wkv_pallas(rb, kb, vb, wb, bb, sb, chunk=c, interpret=interpret)
+    y, sf = _wkv(rb, kb, vb, wb, bb, sb, chunk=c)
     y = y.reshape(B, H, S, dh).transpose(0, 2, 1, 3)
     return y, sf.reshape(B, H, dh, dh)

@@ -10,7 +10,9 @@ Linear-time recurrent transformer with:
   - channel-mix: token-shifted squared-ReLU FFN (classic RWKV).
 
 The recurrence is exactly what `repro/kernels/wkv` implements as a chunked
-Pallas TPU kernel; `impl="scan"` is the jnp oracle path used on CPU.
+Pallas kernel. `timemix_apply(impl=)` takes "scan", the jnp oracle, or
+"pallas", the kernel: compiled on a TPU, interpreted elsewhere
+(repro/kernels/__init__.py).
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import check_backend
 from repro.models.layers import _init_array, rmsnorm_apply, rmsnorm_init
 
 
@@ -107,10 +110,9 @@ def timemix_apply(params, x, num_heads: int, impl: str = "scan",
     B, S, d = x.shape
     x_prev = _token_shift(x, shift_state)
     r, k, v, w, beta = _project_rkvwb(params, x, x_prev, num_heads)
-    if impl == "pallas" or impl == "pallas_interpret":
+    if check_backend("impl", impl, ("scan", "pallas")) == "pallas":
         from repro.kernels.wkv.ops import wkv_chunked
-        y, Sf = wkv_chunked(r, k, v, w, beta, state=wkv_state,
-                            interpret=(impl == "pallas_interpret"))
+        y, Sf = wkv_chunked(r, k, v, w, beta, state=wkv_state)
     else:
         y, Sf = wkv_scan_ref(r, k, v, w, beta, state=wkv_state)
     y = y.astype(x.dtype).reshape(B, S, d)

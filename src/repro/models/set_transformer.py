@@ -22,6 +22,7 @@ from repro.models.layers import (
 )
 
 NEG_INF = -2.0 ** 30
+ATTENTION_BACKENDS = ("xla", "pallas")   # jnp reference, fused kernel
 
 
 def _mha_init(key, d: int, num_heads: int, dtype):
@@ -41,21 +42,20 @@ def _mha_apply(params, xq, xk, num_heads: int, key_bias=None, key_mask=None,
                impl: str = "xla"):
     """xq: (B,N,d), xk: (B,M,d). key_bias: (B,M) additive logit bias.
 
-    impl: "xla" (pure jnp) | "pallas" | "pallas_interpret" — the fused
-    kernel in repro/kernels/set_attention (same convention as the RWKV
-    timemix path)."""
+    impl: "xla" (pure jnp) | "pallas", the fused kernel in
+    repro/kernels/set_attention; the pipeline and the Stage-2 engine
+    check the value where they take it."""
     B, N, d = xq.shape
     M = xk.shape[1]
     dh = d // num_heads
     q = (xq @ params["wq"].astype(xq.dtype)).reshape(B, N, num_heads, dh)
     k = (xk @ params["wk"].astype(xq.dtype)).reshape(B, M, num_heads, dh)
     v = (xk @ params["wv"].astype(xq.dtype)).reshape(B, M, num_heads, dh)
-    if impl == "pallas" or impl == "pallas_interpret":
+    if impl == "pallas":
         from repro.kernels.set_attention.ops import masked_set_attention
         o = masked_set_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), key_bias=key_bias, key_mask=key_mask,
-            interpret=(impl == "pallas_interpret"))
+            v.transpose(0, 2, 1, 3), key_bias=key_bias, key_mask=key_mask)
         o = o.transpose(0, 2, 1, 3).reshape(B, N, d)
     else:
         s = jnp.einsum("bnhd,bmhd->bhnm", q, k).astype(jnp.float32) * (dh ** -0.5)
@@ -124,10 +124,10 @@ def set_transformer_apply(params, x, *, num_heads: int = 4,
     """x: (B, N, d_in) set elements; weights: (B, N) nonneg frequencies;
     mask: (B, N) valid flags. Returns (B, d_out) signature.
 
-    impl selects the attention backend ("xla" | "pallas" |
-    "pallas_interpret"); all three differentiate — the fused kernel has
-    a custom VJP (flash-style recompute backward), so Stage-2 training
-    can run the Pallas path end to end."""
+    impl selects the attention backend ("xla" | "pallas"); both
+    differentiate — the fused kernel has a custom VJP (flash-style
+    recompute backward), so Stage-2 training can run the Pallas path end
+    to end."""
     B, N, _ = x.shape
     key_bias = None
     if weights is not None:

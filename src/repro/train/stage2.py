@@ -11,9 +11,8 @@ inside the jitted train step against one uploaded BBE matrix
 device-side inference batching).
 
 The attention backend is selectable per engine: impl="pallas" runs the
-fused set-attention kernel in BOTH directions (its custom VJP), "xla"
-the jnp reference, "pallas_interpret" the kernel under the interpreter
-(CPU parity testing).
+fused set-attention kernel in BOTH directions (its custom VJP), compiled
+on a TPU and interpreted elsewhere; "xla" runs the jnp reference.
 
 `triplet_row_batch` assembles a training batch from already-selected
 anchor/positive/negative intervals via the same `batch_set_ids` sort
@@ -29,6 +28,8 @@ import numpy as np
 from repro.config import TrainConfig
 from repro.core.pipeline import BBEIndex, batch_set_ids
 from repro.core.signature import SignatureConfig, stage2_loss_from_rows
+from repro.kernels import check_backend
+from repro.models.set_transformer import ATTENTION_BACKENDS
 from repro.train.trainer import Trainer
 
 
@@ -61,7 +62,7 @@ class Stage2Engine:
                  mesh=None, rules: Optional[Dict] = None,
                  donate: bool = False):
         self.sig_cfg = sig_cfg
-        self.impl = impl
+        self.impl = check_backend("impl", impl, ATTENTION_BACKENDS)
         self.matrix = jnp.asarray(matrix)
 
         def loss_fn(p, batch):

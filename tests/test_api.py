@@ -4,24 +4,32 @@ parity acceptance criteria), assignment-kernel impl parity, and the
 SemanticBBVService facade end-to-end on a tiny real pipeline."""
 import os
 
-import jax
 import numpy as np
 import pytest
 
 from repro.api import (
     CPIEstimate, KnowledgeBase, SemanticBBVService, ServiceConfig,
-    SignatureStore, assign_signatures, resolve_assign_impl,
-    resolve_build_impl,
+    SignatureStore, assign_signatures,
 )
 from repro.core.bbe import BBEConfig
+from repro.core.clustering import kmeans, representatives
 from repro.core.crossprog import cpi_accuracy, universal_clustering
 from repro.core.pipeline import PipelineConfig, SemanticBBVPipeline
 from repro.core.signature import SignatureConfig
 from repro.data.perfmodel import INORDER_CPU, interval_cpi
 from repro.data.trace import block_table, trace_program
+from repro.train.stage2 import Stage2Engine
 
 
 # --------------------------------------------------------------- toy data
+
+def _nearest_numpy(x, c):
+    """Numpy nearest-centroid oracle: (assign (N,) int32, dist2 (N,))."""
+    x, c = np.asarray(x, np.float32), np.asarray(c, np.float32)
+    d2 = (np.sum(x * x, -1, keepdims=True) - 2.0 * (x @ c.T)
+          + np.sum(c * c, -1)[None, :])
+    return d2.argmin(-1).astype(np.int32), d2.min(-1).astype(np.float32)
+
 
 def _blob_program(seed, centers, n_per=25, noise=0.05):
     """Synthetic program drawn from shared behavioral blobs; CPI is a
@@ -110,73 +118,71 @@ def test_assign_impl_parity_kernel_vs_numpy():
     rng = np.random.RandomState(3)
     x = rng.randn(37, 16).astype(np.float32)        # non-tile-aligned N
     c = rng.randn(5, 16).astype(np.float32)
-    a_np, d_np = assign_signatures(x, c, impl="numpy")
-    for impl in ("reference", "pallas_interpret"):
+    a_np, d_np = _nearest_numpy(x, c)
+    for impl in ("reference", "pallas"):
         a, d = assign_signatures(x, c, impl=impl)
         np.testing.assert_array_equal(a, a_np, err_msg=impl)
         np.testing.assert_allclose(d, d_np, rtol=1e-4, atol=1e-4,
                                    err_msg=impl)
 
 
-def test_resolve_assign_impl():
-    assert resolve_assign_impl("numpy") == "numpy"
-    resolved = resolve_assign_impl("auto")
-    expected = "pallas" if jax.default_backend() == "tpu" else "reference"
-    assert resolved == expected
-    with pytest.raises(ValueError):
-        resolve_assign_impl("bogus")
-
-
 def test_knowledge_base_attach_uses_kernel_impl(blob_centers):
-    """attach() through impl="pallas_interpret" reproduces the
-    reference-impl fingerprints (kernel runs inside the query path)."""
+    """attach() through assign_impl="pallas" reproduces the
+    reference-impl fingerprints and the numpy oracle's (kernel runs
+    inside the query path)."""
     fingerprints = {}
-    for impl in ("reference", "pallas_interpret", "numpy"):
+    for impl in ("reference", "pallas"):
         store = _filled_store(blob_centers, ["A", "B"])
         kb = KnowledgeBase(store, assign_impl=impl).build(k=3, seed=0)
         sP, cP = _blob_program(9, blob_centers)
         store.add("P", sP, cpis=cP)
         fingerprints[impl] = kb.attach("P")
-    np.testing.assert_array_equal(fingerprints["pallas_interpret"],
-                                  fingerprints["numpy"])
-    np.testing.assert_array_equal(fingerprints["reference"],
-                                  fingerprints["numpy"])
+    a, _ = _nearest_numpy(sP, kb.archetypes)
+    oracle = np.bincount(a, minlength=kb.k) / len(a)
+    np.testing.assert_array_equal(fingerprints["pallas"],
+                                  fingerprints["reference"])
+    np.testing.assert_allclose(fingerprints["reference"], oracle,
+                               atol=1e-12)
 
 
 # ---------------------------------------------------- on-device build
 
-def _align(kb, ref):
-    """Cluster-label bijection: archetype j of `kb` -> nearest archetype
-    of `ref` (k-means labelings are not canonical)."""
-    perm, _ = assign_signatures(kb.archetypes, ref.archetypes,
-                                impl="numpy")
-    assert sorted(perm.tolist()) == list(range(ref.k))
+def _align(archetypes, ref):
+    """Cluster-label bijection: archetype j -> nearest archetype of `ref`
+    (k-means labelings are not canonical)."""
+    perm, _ = _nearest_numpy(archetypes, ref)
+    assert sorted(perm.tolist()) == list(range(len(ref)))
     return perm
 
 
 @pytest.mark.parametrize("impl", ["device", "device_kernel"])
 def test_device_build_cluster_aligned_with_host(blob_centers, impl):
-    """Acceptance: build(impl="device"/"device_kernel") — the jitted
+    """Acceptance: build_impl="device"/"device_kernel" — the jitted
     restart loop over the padded store matrix, Pallas kernels inside for
-    device_kernel — must be cluster-aligned bit-compatible with the
-    legacy host numpy path at tiny k."""
-    host = KnowledgeBase(_filled_store(blob_centers, ["A", "B"]),
-                         build_impl="host").build(k=3, seed=0)
-    dev = KnowledgeBase(_filled_store(blob_centers, ["A", "B"]),
-                        build_impl=impl).build(k=3, seed=0)
-    perm = _align(dev, host)
+    device_kernel — must be cluster-aligned bit-compatible with the host
+    restart loop (`kmeans` + `representatives`) at tiny k."""
+    store = _filled_store(blob_centers, ["A", "B"])
+    x = store.signatures.astype(np.float32)
+    cents, assign, _ = kmeans(x, 3, seed=0)
+    reps = representatives(x, cents, assign)
+    dev = KnowledgeBase(store, build_impl=impl).build(k=3, seed=0)
+    perm = _align(dev.archetypes, cents)
+    rep_cpi = store.cpis[reps].astype(np.float64)
     # identical membership (bit-compatible assignments up to labeling)
     for p in ("A", "B"):
+        rows = store.rows_for(p)
+        w = store.weights[rows].astype(np.float64)
+        host_f = np.zeros(3)
+        np.add.at(host_f, assign[rows], w / w.sum())
         f = np.zeros(3)
         np.add.at(f, perm, dev.fingerprints[p])
-        np.testing.assert_allclose(f, host.fingerprints[p], atol=1e-12,
-                                   err_msg=p)
+        np.testing.assert_allclose(f, host_f, atol=1e-12, err_msg=p)
         assert dev.estimate(p).est_cpi == pytest.approx(
-            host.estimate(p).est_cpi, rel=1e-6)
+            float((host_f * rep_cpi).sum()), rel=1e-6)
     # same representative intervals, module labeling
     np.testing.assert_array_equal(np.sort(dev.rep_global_idx),
-                                  np.sort(host.rep_global_idx))
-    np.testing.assert_allclose(dev.archetypes, host.archetypes[perm],
+                                  np.sort(reps))
+    np.testing.assert_allclose(dev.archetypes, cents[perm],
                                rtol=1e-5, atol=1e-5)
 
 
@@ -194,13 +200,63 @@ def test_device_build_over_grown_padded_store(blob_centers):
                                    atol=1e-12)
 
 
-def test_resolve_build_impl():
-    assert resolve_build_impl("host") == "host"
-    expected = ("device_kernel" if jax.default_backend() == "tpu"
-                else "device")
-    assert resolve_build_impl("auto") == expected
-    with pytest.raises(ValueError):
-        resolve_build_impl("bogus")
+# ------------------------------------------------ backend option values
+
+@pytest.mark.parametrize("option,value", [
+    ("impl", "pallas_interpret"), ("assign_impl", "numpy"),
+    ("assign_impl", "auto"), ("build_impl", "host"), ("build_impl", "auto"),
+])
+def test_retired_backend_value_refused(option, value):
+    """Each backend option takes its reference or its kernel, nothing
+    else: a retired value is refused, naming the two the option takes,
+    where the value enters — a service built from its config, and the
+    Stage-2 engine (impl) or the knowledge base (the other two)."""
+    refused = pytest.raises(ValueError, match=f"^{option} must be one of")
+    sig = SignatureConfig(bbe_dim=8, d_model=8, sig_dim=4, max_set=4,
+                          num_heads=2)
+    bbe = BBEConfig(dim_embeds=(48, 8, 8, 8, 8, 8), num_layers=1,
+                    num_heads=2, bbe_dim=8, max_len=16)
+    with refused:
+        SemanticBBVService.create(
+            ServiceConfig(bbe=bbe, sig=sig, **{option: value}))
+    if option == "impl":
+        with refused:
+            Stage2Engine(sig, None, None, None, None, impl=value)
+    else:
+        with refused:
+            KnowledgeBase(SignatureStore(8), **{option: value})
+
+
+@pytest.mark.parametrize("option,retired,current", [
+    ("assign_impl", "numpy", "reference"),
+    ("assign_impl", "pallas_interpret", "pallas"),
+    ("assign_impl", "auto", "pallas"),
+    ("build_impl", "host", "device"),
+])
+def test_knowledge_base_saved_with_retired_value_loads(
+        tmp_path, blob_centers, option, retired, current):
+    """A knowledge base saved while the option took `retired` loads with
+    the value that replaced it and estimates bit-identically."""
+    store = _filled_store(blob_centers, ["A", "B"])
+    kb = KnowledgeBase(store).build(k=3, seed=0)
+    sP, cP = _blob_program(17, blob_centers)
+    store.add("P", sP, cpis=cP)
+    before = {p: kb.estimate(p) for p in store.programs}
+    setattr(kb, option, retired)           # the meta an older save wrote
+    kb.save(str(tmp_path / "knowledge"))
+    kb2 = KnowledgeBase.load(str(tmp_path / "knowledge"), store)
+    assert getattr(kb2, option) == current
+    for p, e1 in before.items():
+        e2 = kb2.estimate(p)
+        assert e2.est_cpi == e1.est_cpi, p
+        np.testing.assert_array_equal(e2.fingerprint, e1.fingerprint)
+    # a program ingested after the load is assigned by the loaded backend
+    sQ, cQ = _blob_program(18, blob_centers)
+    store.add("Q", sQ, cpis=cQ)
+    f = kb2.estimate("Q").fingerprint
+    a, _ = _nearest_numpy(sQ, kb2.archetypes)
+    np.testing.assert_allclose(f, np.bincount(a, minlength=3) / len(a),
+                               atol=1e-12)
 
 
 # ----------------------------------------------------------- attach_many
@@ -286,9 +342,7 @@ def test_attach_unseen_matches_full_rebuild(blob_centers):
     full.add("P", sP, cpis=cP)
     kb_full = KnowledgeBase(full).build(k=3, seed=0)
     # align: archetype j of the rebuild -> nearest archetype of the base
-    perm, _ = assign_signatures(kb_full.archetypes, kb.archetypes,
-                                impl="numpy")
-    assert sorted(perm.tolist()) == [0, 1, 2]        # a real bijection
+    perm = _align(kb_full.archetypes, kb.archetypes)
     f_rebuild = np.zeros_like(f_attach)
     np.add.at(f_rebuild, perm, kb_full.fingerprints["P"])
     np.testing.assert_allclose(f_attach, f_rebuild, atol=1e-12)
@@ -424,9 +478,9 @@ def test_legacy_shim_matches_knowledge_base(blob_centers):
 # ------------------------------------------------------- service facade
 
 @pytest.fixture(scope="module")
-def tiny_service():
-    """Real (untrained) pipeline over 3 traced programs — the full
-    ingest_blocks -> ingest_intervals -> build -> attach flow."""
+def tiny_world():
+    """Real (untrained) pipeline, the blocks of 3 traced programs, their
+    intervals and ground-truth CPIs."""
     from repro.data.asmgen import spec_programs
     progs = spec_programs("int")[:3]
     bt = block_table(progs)
@@ -439,23 +493,47 @@ def tiny_service():
         sig=SignatureConfig(bbe_dim=32, d_model=32, sig_dim=16, max_set=48,
                             num_heads=2),
         k=6, store_min_capacity=16)
-    svc = SemanticBBVService.create(cfg)
-    svc.ingest_blocks(list(bt.values()))
+    pipe = SemanticBBVPipeline.from_config(cfg.pipeline_config())
+    return pipe, cfg, list(bt.values()), progs, per_prog, cpis
+
+
+def _fresh_service(tiny_world):
+    """A service over the shared pipeline with every block ingested
+    (the pipeline memoizes BBEs) and an empty store."""
+    pipe, cfg, blocks, *_ = tiny_world
+    svc = SemanticBBVService.from_pipeline(pipe, cfg)
+    svc.ingest_blocks(blocks)
+    return svc
+
+
+def _ingest_build_attach(svc, names, per_prog, cpis):
+    """The full facade flow: ingest all but the last program, build, then
+    ingest the held-out last one and attach it (the reuse path)."""
+    rows = [svc.ingest_intervals(n, per_prog[n], cpis=cpis[n])
+            for n in names[:-1]]
+    kb = svc.build()
+    svc.ingest_intervals(names[-1], per_prog[names[-1]],
+                         cpis=cpis[names[-1]])
+    return rows, kb, svc.attach(names[-1])
+
+
+@pytest.fixture(scope="module")
+def tiny_service(tiny_world):
+    """A service in the state after the full facade flow: every program
+    ingested, the knowledge base built and every program fingerprinted."""
+    *_, progs, per_prog, cpis = tiny_world
+    svc = _fresh_service(tiny_world)
+    _ingest_build_attach(svc, [p.name for p in progs], per_prog, cpis)
     return svc, progs, per_prog, cpis
 
 
-def test_service_ingest_build_attach_estimate(tiny_service):
-    svc, progs, per_prog, cpis = tiny_service
+def test_service_ingest_build_attach_estimate(tiny_world):
+    *_, progs, per_prog, cpis = tiny_world
+    svc = _fresh_service(tiny_world)
     names = [p.name for p in progs]
-    for n in names[:-1]:
-        rows = svc.ingest_intervals(n, per_prog[n], cpis=cpis[n])
-        assert len(rows) == 16
-    kb = svc.build()
+    rows, kb, f = _ingest_build_attach(svc, names, per_prog, cpis)
+    assert [len(r) for r in rows] == [16] * (len(names) - 1)
     assert kb.k == 6 and kb.built
-    # reuse path: held-out program ingested AFTER build, then attached
-    svc.ingest_intervals(names[-1], per_prog[names[-1]],
-                         cpis=cpis[names[-1]])
-    f = svc.attach(names[-1])
     np.testing.assert_allclose(f.sum(), 1.0, atol=1e-9)
     for n in names:
         est = svc.estimate(n)
@@ -515,8 +593,7 @@ def test_service_attach_many_before_build_leaves_no_rows(blob_centers):
 def test_service_attach_many_pipelined(tiny_service):
     """Service-level attach_many({program: intervals}) must ingest via
     one add_many + one batched assignment and match what sequential
-    ingest_intervals + attach produces on the same knowledge base.
-    (Runs after the facade tests above have ingested + built.)"""
+    ingest_intervals + attach produces on the same knowledge base."""
     svc, progs, per_prog, cpis = tiny_service
     assert svc.kb.built
     names = [p.name for p in progs]

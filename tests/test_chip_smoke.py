@@ -47,8 +47,8 @@ def world(smoke):
 
 @pytest.fixture(scope="module")
 def served(smoke, world):
-    cfg = ServiceConfig(bbe=TINY_BBE, sig=TINY_SIG, impl="pallas_interpret",
-                        assign_impl="pallas_interpret",
+    cfg = ServiceConfig(bbe=TINY_BBE, sig=TINY_SIG, impl="pallas",
+                        assign_impl="pallas",
                         build_impl="device_kernel", k=14)
     return smoke.run_service(world, cfg)
 
@@ -97,7 +97,7 @@ def test_parity_phase(smoke, served, world):
 
 def test_stage2_phase(smoke, served, world):
     svc, _, _ = served
-    out = smoke.run_stage2(svc, world, "pallas_interpret", steps=2, batch=4)
+    out = smoke.run_stage2(svc, world, "pallas", steps=2, batch=4)
     assert len(out["losses"]) == 2
     assert out["first_loss_rel_diff"] <= smoke.LOSS_RTOL
 

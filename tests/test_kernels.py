@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs jnp oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas vs jnp oracle (on the CPU the
+kernels lower through the Pallas interpreter)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -44,7 +45,7 @@ def test_wkv_matches_reference(B, S, H, dh, chunk, dtype):
     w = jnp.asarray(rng.uniform(0.7, 1.0, (B, S, H, dh)), dtype)
     beta = jnp.asarray(rng.uniform(0, 1, (B, S, H)), dtype)
     y_ref, s_ref = wkv_reference(r, k, v, w, beta)
-    y_k, s_k = wkv_chunked(r, k, v, w, beta, chunk=chunk, interpret=True)
+    y_k, s_k = wkv_chunked(r, k, v, w, beta, chunk=chunk)
     atol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref),
                                atol=atol, rtol=1e-3)
@@ -64,9 +65,9 @@ def test_wkv_state_chaining():
     y_full, s_full = wkv_reference(r, k, v, w, beta)
     h = S // 2
     y1, s1 = wkv_chunked(r[:, :h], k[:, :h], v[:, :h], w[:, :h],
-                         beta[:, :h], chunk=16, interpret=True)
+                         beta[:, :h], chunk=16)
     y2, s2 = wkv_chunked(r[:, h:], k[:, h:], v[:, h:], w[:, h:],
-                         beta[:, h:], state=s1, chunk=16, interpret=True)
+                         beta[:, h:], state=s1, chunk=16)
     np.testing.assert_allclose(np.concatenate([y1, y2], 1),
                                np.asarray(y_full), atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s_full), atol=1e-4,
@@ -97,7 +98,7 @@ def test_flash_matches_reference(B, S, H, K, D, causal, window, bq, bk,
     v = _rand(rng, (B, S, K, D), dtype)
     ref = attention_reference(q, k, v, causal=causal, window=window)
     out = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=bq, block_k=bk, interpret=True)
+                          block_q=bq, block_k=bk)
     atol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=atol,
@@ -141,7 +142,7 @@ def test_set_attention_matches_reference(B, H, N, M, dh, weighted, masked,
     q, k, v, bias, mask = _set_attn_inputs(rng, B, H, N, M, dh, weighted,
                                            masked, dtype)
     ref = set_attention_reference(q, k, v, bias, mask)
-    out = masked_set_attention(q, k, v, bias, mask, interpret=True)
+    out = masked_set_attention(q, k, v, bias, mask)
     atol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=atol,
@@ -149,7 +150,7 @@ def test_set_attention_matches_reference(B, H, N, M, dh, weighted, masked,
 
 
 def test_set_transformer_impl_parity():
-    """Full Stage-2 model: XLA vs fused-kernel interpret path must agree,
+    """Full Stage-2 model: XLA vs the fused kernel must agree,
     weights + mask engaged (the exact configuration the pipeline runs)."""
     from repro.models.set_transformer import (
         set_transformer_apply, set_transformer_init,
@@ -166,7 +167,7 @@ def test_set_transformer_impl_parity():
     y_xla = set_transformer_apply(params, x, num_heads=4, weights=w, mask=m,
                                   impl="xla")
     y_pal = set_transformer_apply(params, x, num_heads=4, weights=w, mask=m,
-                                  impl="pallas_interpret")
+                                  impl="pallas")
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_xla),
                                atol=1e-5, rtol=1e-4)
 
@@ -183,7 +184,7 @@ def test_set_attention_fully_masked_rows_match_reference():
     m[1, :] = False  # one batch row entirely masked
     mask = jnp.asarray(m)
     ref = set_attention_reference(q, k, v, bias, mask)
-    out = masked_set_attention(q, k, v, bias, mask, interpret=True)
+    out = masked_set_attention(q, k, v, bias, mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5,
                                rtol=1e-3)
 
@@ -194,14 +195,14 @@ def test_set_attention_padding_independence():
     rng = np.random.RandomState(7)
     q, k, v, bias, mask = _set_attn_inputs(rng, 2, 2, 9, 21, 16, True, True,
                                            jnp.float32)
-    out = masked_set_attention(q, k, v, bias, mask, interpret=True)
+    out = masked_set_attention(q, k, v, bias, mask)
     pad = 40
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)),
                  constant_values=3.0)  # garbage keys, masked off
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)), constant_values=5.0)
     bp = jnp.pad(bias, ((0, 0), (0, pad)), constant_values=9.0)
     mp = jnp.pad(mask, ((0, 0), (0, pad)))
-    out_p = masked_set_attention(q, kp, vp, bp, mp, interpret=True)
+    out_p = masked_set_attention(q, kp, vp, bp, mp)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out), atol=1e-6)
 
 
@@ -225,7 +226,7 @@ SET_ATTN_GRAD_CASES = [
                          SET_ATTN_GRAD_CASES)
 def test_set_attention_grad_matches_reference(B, H, N, M, dh, weighted,
                                               masked, dtype):
-    """jax.grad through the fused kernel (custom VJP, interpret mode) must
+    """jax.grad through the fused kernel (custom VJP) must
     match autodiff of the jnp oracle for q, k, v AND key_bias — across
     masked/unmasked, weighted/unweighted, and non-tile-aligned sizes."""
     rng = np.random.RandomState(7 * N + M)
@@ -242,7 +243,7 @@ def test_set_attention_grad_matches_reference(B, H, N, M, dh, weighted,
     g_ref = jax.grad(scalar(set_attention_reference),
                      argnums=(0, 1, 2, 3))(q, k, v, bias)
     g_pal = jax.grad(
-        scalar(lambda *a: masked_set_attention(*a, interpret=True)),
+        scalar(lambda *a: masked_set_attention(*a)),
         argnums=(0, 1, 2, 3))(q, k, v, bias)
     atol = 1e-4 if dtype == jnp.float32 else 5e-2
     for name, a, b in zip("dq dk dv dbias".split(), g_ref, g_pal):
@@ -265,8 +266,7 @@ def test_set_attention_masked_key_grads_exactly_zero():
     mask = jnp.asarray(m)
 
     def scalar(q, k, v, b):
-        return jnp.sum(masked_set_attention(q, k, v, b, mask,
-                                            interpret=True) ** 2)
+        return jnp.sum(masked_set_attention(q, k, v, b, mask) ** 2)
 
     _, dk, dv, db = jax.grad(scalar, argnums=(0, 1, 2, 3))(q, k, v, bias)
     dead = ~m
@@ -311,7 +311,7 @@ def test_stage2_loss_grad_impl_parity(weighted, masked):
         return jax.tree_util.tree_leaves_with_path(g)
 
     for (path_x, gx), (_, gp) in zip(grads("xla"),
-                                     grads("pallas_interpret")):
+                                     grads("pallas")):
         np.testing.assert_allclose(
             np.asarray(gp), np.asarray(gx), atol=1e-4, rtol=1e-3,
             err_msg=jax.tree_util.keystr(path_x))
@@ -335,7 +335,7 @@ def test_kmeans_assign_matches_reference(N, d, K, bn, dtype):
     x = _rand(rng, (N, d), dtype)
     c = _rand(rng, (K, d), dtype)
     a_ref, d_ref = kmeans_assign_reference(x, c)
-    a_k, d_k = kmeans_assign(x, c, block_n=bn, interpret=True)
+    a_k, d_k = kmeans_assign(x, c, block_n=bn)
     np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_ref))
     atol = 1e-3 if dtype == jnp.float32 else 1.0
     np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_ref), atol=atol,
@@ -352,7 +352,7 @@ def test_kmeans_update_matches_reference(N, d, K, bn, dtype):
     x = _rand(rng, (N, d), dtype)
     c = _rand(rng, (K, d), dtype)
     valid = jnp.asarray((np.arange(N) < (3 * N) // 4).astype(np.float32))
-    s_k, n_k, i_k = kmeans_update(x, c, valid, block_n=bn, interpret=True)
+    s_k, n_k, i_k = kmeans_update(x, c, valid, block_n=bn)
     s_r, n_r, i_r = kmeans_update_reference(x, c, valid)
     # counts are exact integers; sums/inertia accumulate in fp32
     np.testing.assert_array_equal(np.asarray(n_k), np.asarray(n_r))
@@ -367,5 +367,5 @@ def test_kmeans_update_none_valid_counts_everything():
     rng = np.random.RandomState(7)
     x = jnp.asarray(rng.randn(100, 8).astype(np.float32))
     c = jnp.asarray(rng.randn(4, 8).astype(np.float32))
-    _, counts, _ = kmeans_update(x, c, interpret=True)
+    _, counts, _ = kmeans_update(x, c)
     assert float(jnp.sum(counts)) == 100.0

@@ -128,7 +128,7 @@ def _rel(a, b):
                   / np.linalg.norm(b, axis=-1)).max())
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_balanced_region_signs_as_its_one_thread_interval(impl):
     """T identical threads are T identical copies of each element: equal
     scores and biases, so every attention weight splits evenly over the

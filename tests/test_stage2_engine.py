@@ -130,10 +130,10 @@ def test_engine_checkpoint_exact_resume(tmp_path):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_engine_impl_backends_take_a_step(tmp_path, impl):
-    """Both attention backends must train through the same engine — the
-    interpret path exercises exactly the code the TPU kernel compiles."""
+    """Both attention backends must train through the same engine — on
+    the CPU "pallas" interprets exactly the kernel a TPU compiles."""
     table, ivs = _world(seed=4, n_blocks=32, n_intervals=8)
     index = BBEIndex(table)
     params, specs = signature_init(jax.random.PRNGKey(1), SIG_CFG)

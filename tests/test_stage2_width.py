@@ -120,7 +120,7 @@ def _table(rng):
 
 @pytest.mark.parametrize("longest", [0, 8, 9, 32, 33, 96, 120])
 @pytest.mark.parametrize("kind", ["interval", "region"])
-@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_width_matches_full_max_set(impl, kind, longest):
     """Signatures and CPIs at each row's width against the same batch at
     full max_set (96); 120 entries are cut to 96. Longest sets of 0 are
@@ -143,7 +143,7 @@ def test_width_matches_full_max_set(impl, kind, longest):
 
 
 @pytest.mark.parametrize("kind", ["interval", "region"])
-@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_row_independent_of_batch_mates(impl, kind):
     """A row's signature and CPI are the same bits whether it is signed
     with rows of other widths, in another split into batches, or alone."""

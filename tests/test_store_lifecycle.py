@@ -29,6 +29,14 @@ def blob_centers():
     return (np.random.RandomState(7).randn(3, 8) * 6).astype(np.float32)
 
 
+def _nearest_numpy(x, c):
+    """Numpy nearest-centroid oracle: (assign (N,) int32, dist2 (N,))."""
+    x, c = np.asarray(x, np.float32), np.asarray(c, np.float32)
+    d2 = (np.sum(x * x, -1, keepdims=True) - 2.0 * (x @ c.T)
+          + np.sum(c * c, -1)[None, :])
+    return d2.argmin(-1).astype(np.int32), d2.min(-1).astype(np.float32)
+
+
 def _filled_store(blob_centers, names):
     store = SignatureStore(8, min_capacity=16)
     for i, name in enumerate(names):
@@ -209,7 +217,7 @@ def test_load_pre_lifecycle_checkpoint(tmp_path, blob_centers):
 
 # -------------------------------------------------- masked device build
 
-@pytest.mark.parametrize("impl", ["host", "device", "device_kernel"])
+@pytest.mark.parametrize("impl", ["device", "device_kernel"])
 def test_build_skips_tombstones(blob_centers, impl):
     """A build over a tombstoned store must equal (cluster-aligned) a
     build over a fresh store containing only the live rows — dead rows
@@ -226,9 +234,7 @@ def test_build_skips_tombstones(blob_centers, impl):
         np.testing.assert_allclose(kb.fingerprints[p].sum(), 1.0,
                                    atol=1e-12)
     # the 3 blob centers are recovered despite the holes
-    from repro.api import assign_signatures
-    perm, d2 = assign_signatures(
-        np.asarray(blob_centers, np.float32), kb.archetypes, impl="numpy")
+    perm, d2 = _nearest_numpy(blob_centers, kb.archetypes)
     assert sorted(perm.tolist()) == [0, 1, 2]
     assert (d2 < 0.1).all()
 
@@ -517,7 +523,7 @@ def _mark():
     return m.span_id
 
 
-@pytest.mark.parametrize("impl", ["numpy", "pallas_interpret", "reference"])
+@pytest.mark.parametrize("impl", ["pallas", "reference"])
 def test_incremental_row_assign_matches_whole_store_pass(blob_centers, impl):
     """After every step of a serving sequence the cached labels equal a
     fresh whole-store pass with the same backend (under "reference",
