@@ -19,15 +19,17 @@ inference path's `BBEIndex`:
   representatives) use to stay valid across the store's whole lifetime.
   `version` increments per mutation (`add`/`evict`/`compact`), so
   consumers can cache derived state keyed on it; `row_epoch` increments
-  only when rows move (`compact`), so per-row state survives appends and
-  evictions and is carried through the remap of the latest compaction.
+  only when a compaction drops or moves rows, so per-row state survives
+  appends and evictions and is carried through the remap of the latest
+  compaction.
 
 LIFECYCLE. Long-running serving ingests forever, so the store is no
 longer grow-only: `evict(rows)` tombstones rows (a host bitmap folded
 into the `device_valid` mask, so jitted queries and builds skip dead
-rows with zero extra host round-trips) and `compact()` rebuilds the
-padded matrix from the survivors in ONE device gather, shrinks capacity
-back to the smallest power of two, and returns an old->new row remap.
+rows with zero extra host round-trips) and `compact()` closes the gaps
+in place, moving only the survivors after the first tombstone, shrinks
+capacity back to the smallest power of two, and returns an old->new row
+remap.
 Per-row `inserted_at`/`last_used` stamps against a logical `clock`
 drive the TTL/LRU policies in `repro.api.lifecycle`.
 
@@ -48,6 +50,11 @@ from repro.train.checkpoint import (
 from repro.utils import tracing
 
 _MIN_CAPACITY = 64
+
+# host columns, each with the value an empty slot holds
+_COLUMNS = (("_sigs", 0.0), ("_weights", 0.0), ("_cpis", np.nan),
+            ("_alive", False), ("_uids", 0), ("_inserted_at", 0),
+            ("_last_used", 0))
 
 
 def _capacity_for(n: int, minimum: int = _MIN_CAPACITY) -> int:
@@ -80,9 +87,9 @@ class SignatureStore:
         self.sig_dim = int(sig_dim)
         self.min_capacity = int(min_capacity)
         self.version = 0
-        # bumped by each compaction that moves rows: per-row state
-        # labelled at one epoch is valid at the next only through the
-        # remap that compaction returned
+        # bumped by each compaction that drops or moves rows: per-row
+        # state labelled at one epoch is valid at the next only through
+        # the remap that compaction returned
         self.row_epoch = 0
         self._n = 0
         self._n_dead = 0
@@ -139,23 +146,11 @@ class SignatureStore:
         cap = _capacity_for(n, self.min_capacity)
         if cap == self.capacity:
             return
-        sigs = np.zeros((cap, self.sig_dim), np.float32)
-        sigs[:self._n] = self._sigs[:self._n]
-        weights = np.zeros((cap,), np.float64)
-        weights[:self._n] = self._weights[:self._n]
-        cpis = np.full((cap,), np.nan, np.float32)
-        cpis[:self._n] = self._cpis[:self._n]
-        alive = np.zeros((cap,), bool)
-        alive[:self._n] = self._alive[:self._n]
-        uids = np.zeros((cap,), np.int64)
-        uids[:self._n] = self._uids[:self._n]
-        inserted = np.zeros((cap,), np.int64)
-        inserted[:self._n] = self._inserted_at[:self._n]
-        used = np.zeros((cap,), np.int64)
-        used[:self._n] = self._last_used[:self._n]
-        self._sigs, self._weights, self._cpis = sigs, weights, cpis
-        self._alive, self._uids = alive, uids
-        self._inserted_at, self._last_used = inserted, used
+        for name, fill in _COLUMNS:
+            src = getattr(self, name)
+            dst = np.full((cap,) + src.shape[1:], fill, src.dtype)
+            dst[:self._n] = src[:self._n]
+            setattr(self, name, dst)
         self._device = None
         self._device_valid = None
 
@@ -287,30 +282,40 @@ class SignatureStore:
 
     def compact(self) -> np.ndarray:
         """Drop tombstoned rows and shrink capacity back to the smallest
-        power of two: ONE device gather rebuilds the padded matrix from
-        the survivors (order-preserving, so a compacted store is
-        bit-identical to a fresh store holding only the live rows), host
-        metadata is rebuilt by vectorized fancy-indexing, and fully-
-        evicted programs are dropped from the registry.
+        power of two. Order-preserving, so a compacted store is
+        bit-identical to a fresh store holding only the live rows, and
+        the rows before the first tombstone keep their slots: only the
+        survivors after it are written, into the same host buffers while
+        the capacity holds (into new ones when it shrinks), and the
+        vacated slots are reset to what a fresh store holds there. A
+        resident device matrix is rebuilt by ONE fixed-shape gather.
+        Fully-evicted programs are dropped from the registry.
+
+        Views taken before (`signatures`, `weights`, `cpis`,
+        `alive_mask`, `uids`, `last_used`, `inserted_at`) are not valid
+        after: the compaction writes into the buffers they show.
 
         Returns the old->new row remap: (old_len,) int64, -1 for rows
         that no longer exist. Row `uid`s survive compaction — persisted
         consumers (saved KnowledgeBases) re-resolve through them.
         """
         with tracing.span("store.compact") as s:
-            remap = self._compact()
-            s.add(rows_before=remap.size, rows_after=self._n)
+            remap, moved = self._compact()
+            s.add(rows_before=remap.size, rows_after=self._n,
+                  rows_moved=moved)
             return remap
 
-    def _compact(self) -> np.ndarray:
+    def _compact(self) -> Tuple[np.ndarray, int]:
+        """(remap, number of survivors written to another slot)."""
         old_n = self._n
-        keep = np.flatnonzero(self._alive[:old_n]).astype(np.int64)
+        alive = self._alive[:old_n]
+        keep = np.flatnonzero(alive).astype(np.int64)
         m = int(keep.size)
         new_cap = _capacity_for(m, self.min_capacity)
         remap = np.full(old_n, -1, np.int64)
         remap[keep] = np.arange(m)
         if not self.has_tombstones and new_cap == self.capacity:
-            return remap                      # nothing to do; no bump
+            return remap, 0                   # nothing to do; no bump
 
         if self._device is not None:
             # device-side compaction: one gather over the already-
@@ -322,29 +327,34 @@ class SignatureStore:
             self._device = (jnp.take(self._device, jnp.asarray(idx), axis=0)
                             * jnp.asarray(mask))
             tracing.add(h2d_bytes=idx.nbytes + mask.nbytes)
+            # the gather reads the old matrix, which may share memory with
+            # `_sigs` (a CPU backend uploads without a copy): let it finish
+            # before the host columns are rewritten in place
+            self._device.block_until_ready()
 
-        sigs = np.zeros((new_cap, self.sig_dim), np.float32)
-        sigs[:m] = self._sigs[keep]
-        weights = np.zeros((new_cap,), np.float64)
-        weights[:m] = self._weights[keep]
-        cpis = np.full((new_cap,), np.nan, np.float32)
-        cpis[:m] = self._cpis[keep]
-        alive = np.zeros((new_cap,), bool)
-        alive[:m] = True
-        uids = np.zeros((new_cap,), np.int64)
-        uids[:m] = self._uids[keep]
-        inserted = np.zeros((new_cap,), np.int64)
-        inserted[:m] = self._inserted_at[keep]
-        used = np.zeros((new_cap,), np.int64)
-        used[:m] = self._last_used[keep]
-        self._sigs, self._weights, self._cpis = sigs, weights, cpis
-        self._alive, self._uids = alive, uids
-        self._inserted_at, self._last_used = inserted, used
+        # slots [0, f) hold no tombstone, so their rows stay put; only
+        # the survivors after the first tombstone move, down to [f, m)
+        f = int(np.argmin(alive)) if m < old_n else m
+        tail = keep[f:]
+        in_place = new_cap == self.capacity
+        for name, fill in _COLUMNS:
+            src = getattr(self, name)
+            if in_place:
+                dst = src
+            else:
+                dst = np.full((new_cap,) + src.shape[1:], fill, src.dtype)
+                dst[:f] = src[:f]
+            dst[f:m] = src[tail]
+            dst[m:old_n] = fill
+            setattr(self, name, dst)
 
-        prog_arr = np.asarray(self._program_of_row, object)[keep]
-        self._program_of_row = prog_arr.tolist()
+        names = self._program_of_row
+        names[f:] = [names[i] for i in tail.tolist()]
         new_rows: Dict[str, List[int]] = {}
         for p, old_rows in self._program_rows.items():
+            if old_rows and old_rows[-1] < f:  # ascending, all before f
+                new_rows[p] = old_rows
+                continue
             nr = remap[np.asarray(old_rows, np.int64)]
             nr = nr[nr >= 0]
             if nr.size:
@@ -353,9 +363,11 @@ class SignatureStore:
         self._n = m
         self._n_dead = 0
         self.version += 1
+        # also when no row moved: slots [m, old_n) are free again, and
+        # per-row state kept for them would be read as the next rows'
         self.row_epoch += 1
         self._device_valid = None
-        return remap
+        return remap, m - f
 
     # ------------------------------------------------------------- views
     def rows_for(self, program: str) -> np.ndarray:

@@ -109,38 +109,124 @@ def test_touch_is_metadata_only(blob_centers):
 
 # -------------------------------------------------------------- compaction
 
-def test_compact_bit_identical_to_fresh_store(blob_centers):
-    store = _filled_store(blob_centers, ["A", "B", "C"])
+# dead rows of a 225-row store (programs A, B, C of 75 rows each, capacity
+# 256): the first tombstone anywhere from row 0 to past every survivor
+_DEAD_ROWS = {
+    "tail": np.arange(150, 225),                       # C: nothing moves
+    "head": np.arange(0, 75),                          # A: every row moves
+    "middle": np.arange(75, 150),                      # B
+    "scattered": np.random.RandomState(1).choice(225, size=30,
+                                                 replace=False),
+    "all_dead": np.arange(225),
+    # half the rows at random: capacity shrinks 256 -> 128
+    "shrink": np.random.RandomState(0).choice(225, size=112, replace=False),
+}
+
+
+@pytest.mark.parametrize("case", list(_DEAD_ROWS))
+def test_compact_bit_identical_to_fresh_store(blob_centers, case):
+    names = ["A", "B", "C"]
+    store = _filled_store(blob_centers, names)
     n = len(store)
     _ = store.device_matrix                    # force device residency
-    rng = np.random.RandomState(0)
-    dead = rng.choice(n, size=n // 2, replace=False)
+    dead = _DEAD_ROWS[case]
     keep = np.setdiff1d(np.arange(n), dead)
-    live_sigs = store.signatures[keep].copy()
+    m = keep.size
     live_uids = store.uids[keep].copy()
+    live_inserted = store.inserted_at[keep].copy()
+    live_used = store.last_used[keep].copy()
+    fresh = SignatureStore(8, min_capacity=16)
+    for i, name in enumerate(names):
+        s, c = _blob_program(i, blob_centers)
+        sel = keep[(keep >= 75 * i) & (keep < 75 * (i + 1))] - 75 * i
+        if sel.size:
+            fresh.add(name, s[sel], weights=sel + 1.0, cpis=c[sel])
     store.evict(dead)
     remap = store.compact()
     # remap: -1 at dead rows, dense ascending at survivors
     assert remap.shape == (n,)
     np.testing.assert_array_equal(remap[dead], -1)
-    np.testing.assert_array_equal(remap[keep], np.arange(keep.size))
+    np.testing.assert_array_equal(remap[keep], np.arange(m))
     # dense again, capacity shrunk to the smallest power of two
-    assert len(store) == store.n_alive == keep.size
+    assert len(store) == store.n_alive == len(fresh) == m
     assert not store.has_tombstones
-    assert store.capacity == _capacity_for(keep.size, 16)
-    # bit-identical to a fresh store holding only the live rows — on
-    # host AND on the device matrix rebuilt by the gather
-    np.testing.assert_array_equal(store.signatures, live_sigs)
+    assert store.capacity == fresh.capacity == _capacity_for(m, 16)
+    assert store.capacity == (128 if case == "shrink"
+                              else 16 if case == "all_dead" else 256)
+    # bit-identical to a fresh store holding only the live rows, over the
+    # whole capacity (vacated slots reset) — on host AND on the device
+    # matrix rebuilt by the gather
+    for col in ("_sigs", "_weights", "_cpis", "_alive"):
+        np.testing.assert_array_equal(getattr(store, col),
+                                      getattr(fresh, col), err_msg=col)
     np.testing.assert_array_equal(np.asarray(store.device_matrix),
-                                  np.concatenate([
-                                      live_sigs,
-                                      np.zeros((store.capacity - keep.size,
-                                                8), np.float32)]))
-    # uids survive (the persistent handle)
-    np.testing.assert_array_equal(store.uids, live_uids)
+                                  fresh._sigs)
+    # uids and stamps survive (the persistent handle); padding reads 0
+    for col, live in (("_uids", live_uids), ("_inserted_at", live_inserted),
+                      ("_last_used", live_used)):
+        np.testing.assert_array_equal(getattr(store, col)[:m], live,
+                                      err_msg=col)
+        assert not getattr(store, col)[m:].any(), col
     np.testing.assert_array_equal(store.rows_of_uids(live_uids),
-                                  np.arange(keep.size))
+                                  np.arange(m))
     assert (store.rows_of_uids(np.asarray([10**9])) == -1).all()
+    # registry: fully-evicted programs dropped, the rest renumbered
+    assert store.programs == fresh.programs
+    assert store.program_of_row == fresh.program_of_row
+    for p in fresh.programs:
+        np.testing.assert_array_equal(store.rows_for(p), fresh.rows_for(p))
+    assert store.total_weight == fresh.total_weight
+    # and it keeps working as a store: appends land after the survivors
+    rows = store.add("D", _blob_program(9, blob_centers)[0][:5])
+    np.testing.assert_array_equal(rows, np.arange(m, m + 5))
+    assert store.program_of_row[m:] == ["D"] * 5
+
+
+def _compact_spans(since):
+    return [s.counts for s in tracing.recent()
+            if s.name == "store.compact" and s.span_id > since]
+
+
+@pytest.mark.parametrize("dead, rows_moved", [
+    (np.arange(150, 225), 0),             # tail eviction: m - f = 150 - 150
+    (np.arange(0, 75), 150),              # head: every survivor moves
+    (np.arange(75, 150), 75),             # middle: C moves down onto B
+    (np.asarray([0]), 224),               # one row at the head
+    (np.asarray([10, 200]), 213),         # f = 10, m = 223
+    (np.zeros(0, np.int64), 0),           # no tombstone: nothing to do
+])
+def test_compact_span_counts_rows_moved(blob_centers, dead, rows_moved):
+    """`store.compact`'s `rows_moved` counts the survivors written to
+    another slot: m - f, with f the first tombstone's slot."""
+    store = _filled_store(blob_centers, ["A", "B", "C"])
+    store.evict(dead)
+    since = _mark()
+    store.compact()
+    spans = _compact_spans(since)
+    assert len(spans) == 1
+    assert spans[0]["rows_moved"] == rows_moved
+    assert spans[0]["rows_before"] == 225
+    assert spans[0]["rows_after"] == 225 - dead.size
+
+
+def test_compact_device_gather_reads_rows_before_they_move():
+    """A resident device matrix is gathered from the old one, which a CPU
+    backend shares with the host buffer that compaction then rewrites in
+    place. At this size (60k x 128 rows) the warm gather runs
+    asynchronously on the CPU, so a compaction that does not wait for it
+    gathers rows that have already moved."""
+    rng = np.random.RandomState(0)
+    for _ in range(4):                    # the first compiles the gather
+        store = SignatureStore(128)
+        progs = [rng.randn(20000, 128).astype(np.float32) for _ in range(3)]
+        for name, sigs in zip("ABC", progs):
+            store.add(name, sigs)
+        _ = store.device_matrix
+        store.evict_program("A")          # head: every survivor moves
+        store.compact()
+        np.testing.assert_array_equal(
+            np.asarray(store.device_matrix)[:40000],
+            np.concatenate(progs[1:]))
 
 
 def test_compact_noop_without_tombstones(blob_centers):
@@ -588,6 +674,40 @@ def test_incremental_row_assign_matches_whole_store_pass(blob_centers, impl):
     assert kb.apply_remap(store.compact()) == 0
     check(full_pass=1, rows_assigned=187, padded_rows=256 - 187,
           rows_cached=0)
+
+
+def test_tail_vacuum_then_new_program_matches_whole_store_pass(
+        blob_centers):
+    """A tail eviction moves no row, yet the base's labels of the evicted
+    slots must not survive the vacuum: the next program's rows land in
+    those very slots. Its fingerprint and estimate are bit-identical to
+    a base that assigns the whole store fresh, and only its rows are
+    assigned."""
+    store = _filled_store(blob_centers, ["A", "B"])        # 150 rows
+    kb = KnowledgeBase(store).build(k=3, seed=0)
+    sP, cP = _blob_program(40, blob_centers)               # three phases
+    store.add("P", sP, cpis=cP)
+    kb.attach("P")
+    assert len(kb._all_row_assign()) == 225
+    store.evict_program("P")
+    report = vacuum(store, kb, EvictionPolicy())
+    assert report.compacted and len(store) == 150
+    fresh = KnowledgeBase(store).build(k=3, seed=0)        # same archetypes
+    np.testing.assert_array_equal(fresh.archetypes, kb.archetypes)
+    # one phase only, in the slots P's three phases held
+    sQ, cQ = _blob_program(41, blob_centers[:1], n_per=75)
+    rows = store.add("Q", sQ, cpis=cQ)
+    np.testing.assert_array_equal(rows, np.arange(150, 225))
+    since = _mark()
+    got = kb.estimate("Q")
+    spans = _assign_all_spans(since)
+    assert [(c["rows_assigned"], c["full_pass"]) for c in spans] == [(75, 0)]
+    want = fresh.estimate("Q")
+    np.testing.assert_array_equal(got.fingerprint, want.fingerprint)
+    assert got.est_cpi == want.est_cpi
+    assert np.count_nonzero(got.fingerprint) == 1   # not P's three phases
+    np.testing.assert_array_equal(kb._all_row_assign(),
+                                  fresh._all_row_assign())
 
 
 def test_build_load_and_store_swap_reset_row_assign_cache(tmp_path,

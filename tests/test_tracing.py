@@ -248,8 +248,10 @@ def test_service_counts_by_hand(tiny_run):
          "h2d_bytes": tail * sig * 4 + k * sig * 4,  # chunk, archetypes
          "d2h_bytes": 2 * tail * 4}]
     assert c["kb.fingerprint"] == [{"rows": 10}, {"rows": 20}]
-    # no device matrix is resident after the second add: host compaction
-    assert c["store.compact"] == [{"rows_before": 52, "rows_after": 32}]
+    # no device matrix is resident after the second add: host compaction;
+    # the evicted rows are the last 20, so no survivor moves
+    assert c["store.compact"] == [{"rows_before": 52, "rows_after": 32,
+                                   "rows_moved": 0}]
     assert c["kb.apply_remap"] == [{"repinned": 0}]
     assert svc.store.capacity == 32
     _, epoch, labels = svc.kb._row_assign_cache  # carried through the remap
